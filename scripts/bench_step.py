@@ -81,6 +81,7 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     import repro.workloads as wl
+    from repro import compile_cache
     from repro.configs.ssd_paper import PAPER_SSD
     from repro.core.ssd import sim
     from repro.core.ssd.policies.state import can_pack, default_cell
@@ -98,6 +99,7 @@ def main(argv=None) -> int:
         ap.error("--max-timeline-overhead requires "
                  "--timeline-overhead-check")
 
+    compile_cache.enable()
     cfg = PAPER_SSD.scaled(args.scale)
     n_logical, capacity = _n_logical(cfg), cfg.total_pages
     closed = args.mode == "bursty"

@@ -18,9 +18,11 @@ through explicit `int16`/`int8` casts, and sign-extension preserves
 equality of narrow values, so the widened kernel carry is value-exact
 for both the packed and unpacked `SimState` layouts.
 
-TPU notes (per the Pallas guide): residency gathers/scatters are
-per-lane scalar `pl.load`/`pl.store` with dynamic `pl.ds` indices — TPU
-Pallas has no vector gather. Superseded lanes (host-side hazard plan,
+Residency gathers/scatters are per-lane scalar ref reads and writes at
+dynamic `pl.ds` indices. This is not yet a TPU kernel: the v5e compiler
+refuses the dynamic indexing of loaded vectors (`lba_k[i]`,
+`buf_loc[j]`, `.at[i].set`), which lowers to `dynamic_slice` (DESIGN.md
+§12). Superseded lanes (host-side hazard plan,
 `workloads.compress`) scatter through a clamped index that writes back
 the value just read: drop-mode scatter spelled branchlessly, exact
 because the fori loops are sequential. `interpret=True` runs the same
@@ -71,18 +73,18 @@ def _segment_stream_kernel(arr_ref, lba_ref, isw_ref, src_ref, scat_ref,
 
     def seg_body(s, red):
         row = (pl.ds(s, 1), slice(None))
-        arr_k = pl.load(arr_ref, row)[0]
-        lba_k = pl.load(lba_ref, row)[0]
-        isw_k = pl.load(isw_ref, row)[0]
-        src_k = pl.load(src_ref, row)[0]
-        scat_k = pl.load(scat_ref, row)[0]
+        arr_k = arr_ref[row][0]
+        lba_k = lba_ref[row][0]
+        isw_k = isw_ref[row][0]
+        src_k = src_ref[row][0]
+        scat_k = scat_ref[row][0]
 
         # segment-start residency gather (scalar loads; see module doc)
         def gather(i, bufs):
             old_b, ep_b = bufs
             a = lba_k[i]
-            old_b = old_b.at[i].set(pl.load(loc_o, (pl.ds(a, 1),))[0])
-            ep_b = ep_b.at[i].set(pl.load(lep_o, (pl.ds(a, 1),))[0])
+            old_b = old_b.at[i].set(loc_o[pl.ds(a, 1)][0])
+            ep_b = ep_b.at[i].set(lep_o[pl.ds(a, 1)][0])
             return old_b, ep_b
 
         old_k, ep_k = jax.lax.fori_loop(
@@ -110,7 +112,7 @@ def _segment_stream_kernel(arr_ref, lba_ref, isw_ref, src_ref, scat_ref,
             0, lanes, lane,
             (red, jnp.zeros(lanes, jnp.int32), jnp.zeros(lanes, jnp.int32),
              jnp.zeros(lanes, jnp.float32)))
-        pl.store(lat_ref, row, lat_row[None, :])
+        lat_ref[row] = lat_row[None, :]
 
         # duplicate-free scatter: superseded lanes clamp to the last slot
         # and write back the value just read (branchless drop)
@@ -118,12 +120,10 @@ def _segment_stream_kernel(arr_ref, lba_ref, isw_ref, src_ref, scat_ref,
             a = scat_k[i]
             live = a < n_logical
             idx = jnp.minimum(a, n_logical - 1)
-            cur_l = pl.load(loc_o, (pl.ds(idx, 1),))[0]
-            cur_e = pl.load(lep_o, (pl.ds(idx, 1),))[0]
-            pl.store(loc_o, (pl.ds(idx, 1),),
-                     jnp.where(live, buf_loc[i], cur_l)[None])
-            pl.store(lep_o, (pl.ds(idx, 1),),
-                     jnp.where(live, buf_ep[i], cur_e)[None])
+            cur_l = loc_o[pl.ds(idx, 1)][0]
+            cur_e = lep_o[pl.ds(idx, 1)][0]
+            loc_o[pl.ds(idx, 1)] = jnp.where(live, buf_loc[i], cur_l)[None]
+            lep_o[pl.ds(idx, 1)] = jnp.where(live, buf_ep[i], cur_e)[None]
             return 0
 
         jax.lax.fori_loop(0, lanes, scatter, 0)

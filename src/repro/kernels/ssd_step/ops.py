@@ -2,11 +2,12 @@
 
 Same contract as the engine's own executor: feed it the (S, K) segment
 arrays from `workloads.compress` and a `SimState` (packed or unpacked);
-get back `(latency (S, K), (Reduced, loc, loc_ep))`. On TPU the Pallas
-kernel runs compiled; elsewhere the pure-jnp engine path is the
-production implementation and `interpret=True` exercises the kernel body
-through the Pallas interpreter (the CI equivalence gate — slow, for
-tests only).
+get back `(latency (S, K), (Reduced, loc, loc_ep))`. On TPU this picks
+the Pallas kernel, which the v5e compiler refuses today (`dynamic_slice`,
+DESIGN.md §12): the call fails rather than running the jnp path instead.
+Elsewhere the pure-jnp engine path runs, and `interpret=True` exercises
+the kernel body through the Pallas interpreter (the equivalence gate —
+slow, for tests only).
 """
 from __future__ import annotations
 

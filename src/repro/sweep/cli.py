@@ -47,6 +47,8 @@ geomean summaries gain bootstrap confidence intervals.
 Device sharding: before importing jax the CLI forces
 `--xla_force_host_platform_device_count=<n>` (default: all CPUs) so the
 fleet's cell axis shards across host devices; pass --devices 1 to disable.
+The flag shapes only JAX's CPU platform: on a TPU machine the cells shard
+over the chips. The compilation cache is placed by `repro.compile_cache`.
 Results land in `BENCH_<name>.json` (sweep.store) for the cross-PR perf
 trajectory.
 """
@@ -150,7 +152,7 @@ def _parse(argv):
     ap.add_argument("--profile", default=None, metavar="DIR",
                     help="capture a jax.profiler device trace of the "
                     "sweep into DIR (TensorBoard/Perfetto-openable; "
-                    "degrades to a no-op without a profiler backend)")
+                    "the run fails if the capture cannot start)")
     ap.add_argument("--bench", action="store_true",
                     help="also wall-clock fleet vs looped eval_cell")
     ap.add_argument("--name", default=None, help="benchmark artifact name "
@@ -175,7 +177,7 @@ def main(argv=None) -> int:
         _force_host_devices(n_dev)
 
     # heavy imports only after XLA_FLAGS is pinned
-    from repro import workloads
+    from repro import compile_cache, workloads
     from repro.configs.ssd_paper import PAPER_SSD
     from repro.sweep.grid import expand_grid, named_grid
     from repro.sweep.report import (endurance_summary, hostcache_summary,
@@ -186,6 +188,7 @@ def main(argv=None) -> int:
 
     from repro.core.ssd.endurance.spec import EnduranceSpec
     from repro.core.ssd.policies import baseline_of, get_entry, policy_names
+    compile_cache.enable()
 
     if args.list_policies:
         print(f"{'policy':<10}{'composition':<42}{'baseline':<10}doc")

@@ -130,7 +130,8 @@ def run_sweep(cfg: SSDConfig, points: Sequence[SweepPoint], *,
     wall-clocks are measured through `telemetry.spans` — install a Tracer
     to collect the sweep's span tree; `timings` keeps working without
     one. Each timings row also carries `compiles`: how many fresh fleet
-    compilations that group's dispatch triggered, plus the group's
+    compilations that group's dispatch triggered, `devices`: how many
+    devices its stacked cells were laid over, plus the group's
     throughput (`ops_per_s` over the padded length, `cells_per_s`) and
     which raw-speed knobs applied (`t_scan`, `packed`).
 
@@ -223,6 +224,7 @@ def run_sweep(cfg: SSDConfig, points: Sequence[SweepPoint], *,
                 "cells": len(grp["pts"]), "pad": grp["pad"],
                 "t_len": grp["t_len"], "t_scan": grp["t_scan"],
                 "packed": grp["packed"], "exec_path": grp["exec_path"],
+                "devices": grp["devices"],
                 "dispatch_s": round(grp["dispatch_s"], 4),
                 "block_s": round(block_s, 4),
                 # ops/s credits the full padded length each cell covers
@@ -294,6 +296,7 @@ def run_sweep(cfg: SSDConfig, points: Sequence[SweepPoint], *,
                         "summ": summ, "names": names, "mode": mode,
                         "spec": spec, "t_len": _t_len, "pad": pad,
                         "t_scan": t_scan, "packed": pack_grp,
+                        "devices": len(ops["lba"].sharding.device_set),
                         "exec_path": ("segment" if t_scan < _t_len
                                       else "per_op"),
                         "dispatch_s": rec["dur_s"],

@@ -11,9 +11,8 @@ without making profiling a dependency:
   `profile.start` / `profile.stop` span events mark the captured region
   in the host span tree, so a Chrome-trace export of the spans
   (`telemetry.export.chrome_trace`) and the device trace line up by
-  wall-clock. Degrades to a no-op (with a `profile.unavailable` event)
-  when the profiler backend is missing — profiling must never fail a
-  run.
+  wall-clock. A capture that was asked for and cannot start raises: a
+  run that silently lost its device trace would pass for a traced one.
 * `device_memory_stats()` — best-effort per-device live-memory
   snapshot (`Device.memory_stats()`; empty on backends without it).
 * `dispatch_stats()` — process-wide compile/dispatch counters from
@@ -40,28 +39,19 @@ __all__ = ["profile", "device_memory_stats", "dispatch_stats",
 @contextlib.contextmanager
 def profile(trace_dir: Optional[str]):
     """Capture a `jax.profiler` trace of the enclosed region into
-    `trace_dir` (None — and any backend failure — degrades to a no-op).
-    Yields True when a capture is actually running."""
+    `trace_dir` (None: no capture). A failure to start or stop the
+    capture propagates. Yields True when a capture is running."""
     if trace_dir is None:
         yield False
         return
-    try:
-        import jax
-        jax.profiler.start_trace(trace_dir)
-    except Exception as e:             # missing backend, double-start, ...
-        spans.event("profile.unavailable", "profile", error=str(e))
-        yield False
-        return
+    import jax
+    jax.profiler.start_trace(trace_dir)
     spans.event("profile.start", "profile", trace_dir=trace_dir)
     try:
         yield True
     finally:
-        try:
-            jax.profiler.stop_trace()
-        except Exception as e:
-            spans.event("profile.stop_failed", "profile", error=str(e))
-        else:
-            spans.event("profile.stop", "profile", trace_dir=trace_dir)
+        jax.profiler.stop_trace()
+        spans.event("profile.stop", "profile", trace_dir=trace_dir)
 
 
 def device_memory_stats() -> Dict[str, Dict]:

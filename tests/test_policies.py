@@ -11,6 +11,7 @@ normalization, and runner group timings.
 """
 import numpy as np
 import pytest
+import jax
 import jax.numpy as jnp
 
 from golden_sim import golden_run_trace
@@ -177,6 +178,7 @@ class TestEveryPolicyEndToEnd:
         for g in timings:
             assert g["dispatch_s"] >= 0 and g["block_s"] >= 0
             assert "+" in g["composition"]
+            assert g["devices"] == len(jax.devices())
 
     def test_bounded_dispatch_window_matches_unbounded(self):
         from repro.sweep.runner import run_sweep

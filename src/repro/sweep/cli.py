@@ -388,7 +388,6 @@ def main(argv=None) -> int:
                                 trace_cache=cache, timings=group_timings,
                                 timeline_ops=args.timeline,
                                 timelines=timelines)
-            profiling.emit_device_events("sweep.done")
         overhead = None
         if args.timeline_overhead_check:
             # warm-vs-warm: the main run above compiled the telemetry-on

@@ -5,11 +5,14 @@ Three layers, separable by dependency weight:
 
 * `spans` — a nested context-manager span tracer (stdlib only). One
   process-wide active tracer (installed via `Tracer.activate()`); every
-  instrumented component (`sweep.runner` dispatch/block, `search.tune`
-  rounds, `workloads` parse/build/cache-hit) records into it when one is
-  active and degrades to a plain wall-clock measurement otherwise, so the
-  legacy BENCH keys (`wall_s`, `group_timings`, `dispatch_s`, `block_s`)
-  are now *derived views* over spans.
+  instrumented component (`sweep.runner` run/group/dispatch/block,
+  `search.tune` rounds, `workloads` parse/build/cache-hit) records into
+  it when one is active and degrades to a plain wall-clock measurement
+  otherwise, so the legacy BENCH keys (`wall_s`, `group_timings`,
+  `dispatch_s`, `block_s`) are now *derived views* over spans. With a
+  tracer active the sweep runner also records each fleet's device work
+  (`device.scan`, `device.tail`, via `Tracer.record`) and the jaxpr
+  traces and backend compiles of each of its phases.
 * `probe` — the in-scan probe engine (imports jax; NOT imported by this
   package `__init__`, which stays jax-free so `repro.sweep`'s
   import-before-XLA_FLAGS contract holds). `TimelineState` is an optional
@@ -27,8 +30,8 @@ Three layers, separable by dependency weight:
   (`BENCH_history.json`, stdlib-only; DESIGN.md §13) every sweep /
   search / bench_step run appends to, gated by
   `python -m repro.telemetry.history --check`.
-* `profiling` — opt-in `jax.profiler` capture + device memory/compile
-  stats posted as span events (jax imported lazily; DESIGN.md §13).
+* `profiling` — opt-in `jax.profiler` capture (jax imported lazily;
+  DESIGN.md §13).
 """
 from repro.telemetry.export import (chrome_trace, round_floats,
                                     timeline_payload)

@@ -59,8 +59,10 @@ def timeline_payload(cells: Dict[str, Dict], *, window_ops: int,
 
 def chrome_trace(spans: List[Dict], path: str) -> str:
     """Write a span list (telemetry.spans schema) as a Chrome
-    trace-event file; returns the path. Atomic (temp + rename) like
-    every other artifact writer."""
+    trace-event file; returns the path. Host spans go on track (`tid`)
+    0; `device`-category spans (the sweep runner's `device.scan` /
+    `device.tail`) on track 1, so device occupancy shows under the host
+    phases. Atomic (temp + rename) like every other artifact writer."""
     events = []
     for rec in spans:
         ev = {
@@ -69,7 +71,7 @@ def chrome_trace(spans: List[Dict], path: str) -> str:
             "ph": "X" if rec.get("dur_s", 0.0) > 0 else "i",
             "ts": round(rec["t0_s"] * 1e6, 1),      # µs
             "pid": 0,
-            "tid": 0,
+            "tid": 1 if rec.get("cat") == "device" else 0,
             "args": rec.get("args", {}),
         }
         if ev["ph"] == "X":

@@ -9,6 +9,10 @@ schema (DESIGN.md §11):
 `t0_s` is relative to the tracer's construction; `parent` is the index of
 the enclosing span in the tracer's `spans` list (None at top level);
 instant events (`event`, e.g. a trace-cache hit) carry `dur_s == 0.0`.
+Intervals known only after they end — a fleet's device work, stamped by
+the sweep runner's completion watcher — are added with `Tracer.record`;
+they lie on no host stack (`depth == -1`, `parent` None), so any host
+span open at the same instant is deeper.
 
 Instrumented call sites use the module-level `span(...)` / `event(...)`
 helpers, which record into the process's *active* tracer when one is
@@ -16,13 +20,17 @@ installed (`Tracer.activate()`, a context manager) and otherwise degrade
 to a plain measurement: `span` always yields a mutable record dict whose
 `dur_s` is filled on exit, so callers that feed derived views (the
 runner's `dispatch_s`/`block_s`, the tuner's `wall_s`) read the same
-number whether or not anybody is tracing. stdlib-only — the workload
-layer (numpy-only by contract) may import this freely.
+number whether or not anybody is tracing. A tracer's spans also enter
+a `jax.profiler.TraceAnnotation` of the same name when jax is already
+imported, so a profiler capture shows them on its own clock; this module
+never imports jax itself. stdlib-only — the workload layer (numpy-only
+by contract) may import this freely.
 """
 from __future__ import annotations
 
 import contextlib
 import contextvars
+import sys
 import time
 from typing import Dict, List, Optional
 
@@ -70,12 +78,28 @@ class Tracer:
         idx = len(self.spans)
         self.spans.append(rec)
         self._stack.append(idx)
+        jax = sys.modules.get("jax")
+        annotate = (jax.profiler.TraceAnnotation(name)
+                    if hasattr(jax, "profiler") else contextlib.nullcontext())
         t0 = time.perf_counter()
         try:
-            yield rec
+            with annotate:
+                yield rec
         finally:
             rec["dur_s"] = time.perf_counter() - t0
             self._stack.pop()
+
+    def record(self, name: str, cat: str, t0: float, t1: float,
+               **args) -> Dict:
+        """Add a span that ran from `t0` to `t1` (`time.perf_counter()`
+        readings), for intervals known only after they end. It lies on no
+        host stack: `depth` -1, `parent` None. Call from the thread that
+        opens the tracer's spans, so `parent` indices stay valid."""
+        rec = {"name": name, "cat": cat, "t0_s": t0 - self._t0,
+               "dur_s": t1 - t0, "depth": -1, "parent": None,
+               "args": dict(args)}
+        self.spans.append(rec)
+        return rec
 
     def event(self, name: str, cat: str = "", **args) -> Dict:
         """Record an instant event (a zero-duration span)."""

@@ -258,6 +258,166 @@ class TestSpans:
             pass
         assert rec["dur_s"] >= 0.0
 
+    def test_record_lies_on_no_host_stack(self):
+        """`Tracer.record` adds a span with explicit clock readings at
+        depth -1, and host spans opened after it keep their parents."""
+        import time
+        tr = Tracer()
+        with tr.activate():
+            with span("outer", "test"):
+                t0 = time.perf_counter()
+                rec = tr.record("device.x", "device", t0, t0 + 0.5, k=2)
+                with span("inner", "test"):
+                    pass
+        names = [s["name"] for s in tr.spans]
+        assert names == ["outer", "device.x", "inner"]
+        assert rec["depth"] == -1 and rec["parent"] is None
+        assert rec["dur_s"] == pytest.approx(0.5)
+        assert rec["args"] == {"k": 2}
+        inner = tr.spans[2]
+        assert inner["parent"] == 0 and inner["depth"] == 1
+        assert rec["t0_s"] >= tr.spans[0]["t0_s"]
+
+    def test_chrome_trace_puts_device_spans_on_their_own_track(
+            self, tmp_path):
+        from repro.telemetry import chrome_trace
+        tr = Tracer()
+        with tr.activate():
+            with span("sweep.run", "sweep"):
+                pass
+        tr.record("device.scan", "device", tr._t0, tr._t0 + 1.0)
+        path = chrome_trace(tr.to_json(), str(tmp_path / "t.json"))
+        with open(path) as f:
+            tids = {e["name"]: e["tid"] for e in json.load(f)["traceEvents"]}
+        assert tids == {"sweep.run": 0, "device.scan": 1}
+
+
+def _sweep_points():
+    from repro.sweep.grid import SweepPoint
+    return [SweepPoint(trace=t, mode="daily", policy=p, seed=3)
+            for p in ("baseline", "ips") for t in ("hm_0", "hm_1")]
+
+
+def _sweep(tracer=None, progress=None):
+    """A 2-fleet daily sweep at a CPU size, traced when `tracer` is
+    given; returns (results, timings)."""
+    import contextlib
+    from repro import workloads
+    from repro.sweep.runner import run_sweep
+    timings = []
+    with (tracer.activate() if tracer else contextlib.nullcontext()):
+        res = run_sweep(CFG, _sweep_points(), max_ops=2048,
+                        timings=timings, progress=progress,
+                        trace_cache=workloads.TraceCache(use_disk=False))
+    return res, timings
+
+
+@pytest.fixture(scope="module")
+def sweeps():
+    """An untraced sweep, then two traced ones, and the live thread
+    counts seen from inside the untraced and the first traced sweep."""
+    live = {"untraced": [], "traced": []}
+    before = threading.active_count()
+    plain, _ = _sweep(progress=lambda _: live["untraced"].append(
+        threading.active_count()))
+    import jax
+    traces = []
+
+    def on_duration(event, _secs, **_):
+        if event == "/jax/core/compile/jaxpr_trace_duration":
+            traces.append(event)
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    try:
+        first = Tracer()
+        traced, timings = _sweep(first, progress=lambda _: live[
+            "traced"].append(threading.active_count()))
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_duration)
+    again = Tracer()
+    _sweep(again)
+    return {"plain": plain, "traced": traced, "timings": timings,
+            "first": first, "first_traces": len(traces), "again": again,
+            "live": live, "before": before,
+            "after": threading.active_count()}
+
+
+class TestSweepDeviceSpans:
+    """The sweep runner's device completion spans and phase counters
+    (DESIGN.md §13)."""
+
+    def test_one_scan_and_one_tail_per_fleet_in_dispatch_order(
+            self, sweeps):
+        spans = sweeps["first"].spans
+        run = [s for s in spans if s["name"] == "sweep.run"]
+        assert len(run) == 1
+        lo, hi = run[0]["t0_s"], run[0]["t0_s"] + run[0]["dur_s"]
+        dispatched = [s["args"]["group"] for s in spans
+                      if s["name"] == "sweep.dispatch"]
+        dev = sorted((s for s in spans if s["cat"] == "device"),
+                     key=lambda s: s["t0_s"])
+        assert [s["name"] for s in dev] == \
+            ["device.scan", "device.tail"] * len(dispatched)
+        assert [s["args"]["group"] for s in dev[::2]] == dispatched
+        assert [s["args"]["group"] for s in dev[1::2]] == dispatched
+        for a, b in zip(dev, dev[1:]):
+            assert a["t0_s"] + a["dur_s"] <= b["t0_s"] + 1e-9
+        for s in dev:
+            assert s["dur_s"] >= 0.0 and s["depth"] == -1
+            assert lo <= s["t0_s"] and s["t0_s"] + s["dur_s"] <= hi
+        t_rows = {g["policies"]: g for g in sweeps["timings"]}
+        for s in dev[::2]:
+            row = t_rows[s["args"]["group"]]
+            assert (s["args"]["t_scan"], s["args"]["t_len"],
+                    s["args"]["exec_path"]) == \
+                (row["t_scan"], row["t_len"], row["exec_path"])
+
+    def test_phase_spans_nest_under_the_run(self, sweeps):
+        spans = sweeps["first"].spans
+        run_idx = [i for i, s in enumerate(spans)
+                   if s["name"] == "sweep.run"][0]
+        for s in spans:
+            if s["name"] in ("sweep.group", "sweep.dispatch",
+                             "sweep.block"):
+                assert s["parent"] == run_idx
+        # every trace is built in the grouping loop; later lookups are
+        # instant cache-hit events
+        builds = [s for s in spans if s["cat"] == "workload"
+                  and s["dur_s"] > 0
+                  and spans[s["parent"]]["cat"] != "workload"]
+        group = [i for i, s in enumerate(spans)
+                 if s["name"] == "sweep.group"][0]
+        assert builds and all(s["parent"] == group for s in builds)
+
+    def test_no_tracer_starts_no_thread_and_changes_no_result(self, sweeps):
+        before = sweeps["before"]
+        assert sweeps["live"]["untraced"] and \
+            set(sweeps["live"]["untraced"]) == {before}
+        # the watcher is alive while a traced sweep dispatches, and gone
+        # after it returns
+        assert set(sweeps["live"]["traced"]) == {before + 1}
+        assert sweeps["after"] == before
+        plain, traced = sweeps["plain"], sweeps["traced"]
+        assert plain.keys() == traced.keys()
+        for pt in plain:
+            assert plain[pt] == traced[pt], pt
+
+    def test_counters_count_traces_and_repeat_without_compiles(
+            self, sweeps):
+        def phases(tracer):
+            return [s for s in tracer.spans if s["name"] in
+                    ("sweep.group", "sweep.dispatch", "sweep.block")]
+        first = phases(sweeps["first"])
+        assert all("jaxpr_traces" in s["args"] and
+                   "backend_compiles" in s["args"] for s in first)
+        # the phases hold every trace the sweep makes
+        assert sum(s["args"]["jaxpr_traces"] for s in first) == \
+            sweeps["first_traces"] > 0
+        again = [s for s in sweeps["again"].spans
+                 if s["name"] == "sweep.dispatch"]
+        assert again and all(s["args"]["compiles"] == 0 for s in again)
+        assert all(s["args"]["backend_compiles"] == 0 for s in again)
+
 
 class TestSegmentWindows:
     """Segment-aware telemetry (DESIGN.md §13): the compressed segment

@@ -165,6 +165,54 @@ def _reachable(comps: dict, roots) -> set:
     return seen
 
 
+def _scan_loop(hlo: str, comps: dict, cells_per_chip: int) -> set:
+    """Computations of the fleet scan: the one loop that carries each
+    chip's residency maps."""
+    scans = [line for line in hlo.splitlines() if " while(" in line
+             and f"s8[{cells_per_chip},{N_LOGICAL}]"
+             in line.split(" while(")[0]]
+    assert len(scans) == 1, scans
+    loop = _reachable(comps, re.findall(r"(?:condition|body)=%([\w.\-]+)",
+                                        scans[0]))
+    assert loop
+    return loop
+
+
+def _indexed_operands(comps: dict, loop) -> list:
+    """(op, operand shape) of every scatter and gather in `loop`."""
+    found = []
+    define = re.compile(r"^\s*(?:ROOT )?%([\w.\-]+) = (\w+\[[\d,]*\])", re.M)
+    for c in loop:
+        shapes = dict(define.findall(comps[c]))
+        for op, arg in re.findall(r" (scatter|gather)\(%([\w.\-]+)",
+                                  comps[c]):
+            found.append((op, shapes.get(arg)))
+    return found
+
+
+@pytest.mark.parametrize("policy", ["baseline", "ips", "ips_agc", "coop"])
+def test_scan_step_has_no_plane_scatter(topo, no_persistent_cache, policy):
+    """The step core reads and writes the (C, P) plane carry with masked
+    lane ops (policies.engine.lane_get/lane_set/lane_add). Indexed per
+    cell instead, each read is a batched gather and each write a batched
+    scatter, a serial fusion of ~0.9 us on the chip. Only the residency
+    map's gather and scatter may stay in the daily scan."""
+    one = SingleDeviceSharding(topo.devices[0])
+    point = SweepPoint(trace="hm_0", mode="daily", policy=policy)
+    n_cells, planes = 11, CFG.num_planes
+    hlo = _compile_trim(point, n_cells, 122880, one).as_text()
+    comps = _computations(hlo)
+    found = _indexed_operands(comps, _scan_loop(hlo, comps, n_cells))
+    assert all(shape for _, shape in found), found
+    plane = [(op, shape) for op, shape in found
+             if shape.endswith(f"[{n_cells},{planes}]")]
+    assert not plane, plane
+    scatters = [shape for op, shape in found if op == "scatter"]
+    assert len(scatters) <= 2, scatters
+    assert all(shape.endswith(f"[{n_cells},{N_LOGICAL}]")
+               for shape in scatters), scatters
+
+
 def test_four_chip_scan_has_no_collectives(topo, no_persistent_cache):
     """Cells are independent, so a fleet laid over four chips along
     ("cells",) must scan with no cross-chip traffic in the loop. (The
@@ -176,13 +224,7 @@ def test_four_chip_scan_has_no_collectives(topo, no_persistent_cache):
     point = SweepPoint(trace="hm_0", mode="daily", policy="ips")
     hlo = _compile_trim(point, 12, 122880, cells).as_text()
     comps = _computations(hlo)
-    # the scan is the loop that carries each chip's 3 residency maps
-    scans = [line for line in hlo.splitlines() if " while(" in line
-             and f"s8[3,{N_LOGICAL}]" in line.split(" while(")[0]]
-    assert len(scans) == 1, scans
-    loop = _reachable(comps, re.findall(r"(?:condition|body)=%([\w.\-]+)",
-                                        scans[0]))
-    assert loop
+    loop = _scan_loop(hlo, comps, 3)     # each chip carries 3 cells' maps
     found = {(c, op) for c in loop for op in COLLECTIVES
              if re.search(rf" {op}(-start)?\(", comps[c])}
     assert not found, found

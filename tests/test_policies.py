@@ -22,6 +22,7 @@ from repro.core.ssd.policies import (PAPER_POLICIES, PolicySpec,
                                      register, resolve_spec,
                                      state_fields_used, tracked_region,
                                      validate_spec)
+from repro.core.ssd.policies.engine import lane_add, lane_get, lane_set
 from repro.core.ssd.sim import (CTR, SimState, default_params, flush_cache,
                                 run_trace, summarize)
 from repro.core.ssd.workloads import make_trace, truncate_trace
@@ -301,3 +302,83 @@ class TestSummaryThroughEngine:
                              {"is_write": jnp.asarray(trace["is_write"])},
                              st)
             assert float(summ["wa_paper"]) >= 1.0 - 1e-6
+
+
+class TestMaskedLaneOps:
+    """The step core reads and writes plane-indexed state through masked
+    lane ops (engine.lane_get/lane_set/lane_add) instead of `x[i]` /
+    `x.at[i]`. They must be the indexing ops bit for bit, vmapped over a
+    fleet's cells and not, for every carry dtype: -0.0, infinities and
+    NaN payloads read back unchanged, narrow integers wrap alike, and the
+    valid_mig sequence (set at the plane, add at the old plane, add at
+    the plane) agrees where the two planes coincide."""
+    CELLS, P = 11, 128
+
+    def _case(self, dtype, trailing, seed=5):
+        rng = np.random.default_rng(seed)
+        shape = (self.CELLS, self.P) + trailing
+        if np.issubdtype(dtype, np.floating):
+            x = rng.normal(0.0, 1e3, shape).astype(dtype)
+            v = rng.normal(0.0, 1e3, (self.CELLS,) + trailing).astype(dtype)
+            specials = np.array([-0.0, np.inf, -np.inf, np.nan, 0.0],
+                                dtype)
+            v.reshape(self.CELLS, -1)[:len(specials), 0] = specials
+            d = rng.normal(0.0, 1.0, (self.CELLS,) + trailing).astype(dtype)
+            d.reshape(self.CELLS, -1)[0, 0] = np.float32(-0.0)
+        else:
+            info = np.iinfo(dtype)
+            x = rng.integers(info.min, info.max, shape,
+                             endpoint=True).astype(dtype)
+            v = rng.integers(info.min, info.max, (self.CELLS,) + trailing,
+                             endpoint=True).astype(dtype)
+            d = rng.integers(-3, 4, (self.CELLS,) + trailing).astype(dtype)
+            v.reshape(self.CELLS, -1)[:2, 0] = [info.max, info.min]
+        plane = rng.integers(0, self.P, self.CELLS).astype(np.int32)
+        old = rng.integers(0, self.P, self.CELLS).astype(np.int32)
+        old[::3] = plane[::3]                  # old plane == plane
+        # the specials also sit in the carry, at the planes read back
+        flat = x.reshape(self.CELLS, self.P, -1)
+        flat[np.arange(self.CELLS), plane, 0] = v.reshape(self.CELLS, -1)[:, 0]
+        return x, v, d, plane, old
+
+    @staticmethod
+    def _masked(x, v, d, plane, old):
+        lanes = jnp.arange(x.shape[0], dtype=jnp.int32)
+        at_p, at_old = lanes == plane, lanes == old
+        seq = lane_add(lane_add(lane_set(x, at_p, v), at_old, -d), at_p, d)
+        return lane_get(x, at_p), lane_set(x, at_p, v), seq
+
+    @staticmethod
+    def _indexed(x, v, d, plane, old):
+        seq = x.at[plane].set(v).at[old].add(-d).at[plane].add(d)
+        return x[plane], x.at[plane].set(v), seq
+
+    @staticmethod
+    def _same_bits(a, b):
+        a, b = np.asarray(a), np.asarray(b)
+        return a.dtype == b.dtype and a.shape == b.shape and \
+            a.tobytes() == b.tobytes()
+
+    @pytest.mark.parametrize("batched", [False, True], ids=["cell", "vmap"])
+    @pytest.mark.parametrize("trailing", [(), (8,)], ids=["row", "rows"])
+    @pytest.mark.parametrize("dtype", [np.int16, np.int32, np.float32])
+    def test_matches_indexing_bit_for_bit(self, dtype, trailing, batched):
+        x, v, d, plane, old = self._case(dtype, trailing)
+        if batched:
+            got = jax.jit(jax.vmap(self._masked))(x, v, d, plane, old)
+            want = jax.jit(jax.vmap(self._indexed))(x, v, d, plane, old)
+        else:
+            masked, indexed = jax.jit(self._masked), jax.jit(self._indexed)
+            per_cell = [(masked(*a), indexed(*a))
+                        for a in zip(x, v, d, plane, old)]
+            got = [np.stack([np.asarray(g[k]) for g, _ in per_cell])
+                   for k in range(3)]
+            want = [np.stack([np.asarray(w[k]) for _, w in per_cell])
+                    for k in range(3)]
+        for name, g, w in zip(("get", "set", "set/add/add"), got, want):
+            assert self._same_bits(g, w), name
+        # the specials really were read: -0.0 keeps its sign bit
+        if dtype == np.float32:
+            read = np.asarray(got[0]).reshape(self.CELLS, -1)[:, 0]
+            assert np.signbit(read[0]) and read[0] == 0.0
+            assert np.isinf(read[1]) and np.isnan(read[3])

@@ -5,10 +5,14 @@
 * a traffic mix is `<bench>/traffic/<traffic>.json`;
 * a per-layer metric is a reader `<bench>/metrics/<metric>.py` that
   defines `read(run)` and returns a number, or None where it finds
-  nothing to read.
+  nothing to read;
+* a plain reference is `<bench>/references/<name>.py`, named by the
+  configuration's `"reference"` key (`paper` where it names none). It
+  defines `results(config, traffic, points, ftype, device)`: one summary
+  dict per sweep point, with every key the program reports for it.
 
-Adding a cell, a configuration, a traffic mix or a metric means adding
-files and entries; nothing here changes.
+Adding a cell, a configuration, a traffic mix, a metric or a reference
+means adding files and entries; nothing here changes.
 """
 from __future__ import annotations
 
@@ -20,6 +24,7 @@ from typing import Callable, Dict, List, Optional
 
 BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(BENCH_DIR)
+DEFAULT_REFERENCE = "paper"
 
 
 @dataclasses.dataclass
@@ -76,17 +81,28 @@ def load_cell(name: str, root: str = ROOT) -> Cell:
         bench_dir=bench_dir)
 
 
-def metric_reader(bench_dir: str, name: str) -> Callable:
-    """The `read` function of `<bench>/metrics/<name>.py`."""
-    path = os.path.join(bench_dir, "metrics", f"{name}.py")
-    mod_name = "bench_metric_" + "".join(
+def _module(bench_dir: str, kind: str, name: str):
+    """The module `<bench>/<kind>/<name>.py`."""
+    path = os.path.join(bench_dir, kind, f"{name}.py")
+    mod_name = f"bench_{kind}_" + "".join(
         c if c.isalnum() else "_" for c in name)
     spec = importlib.util.spec_from_file_location(mod_name, path)
     if spec is None or spec.loader is None:
         raise FileNotFoundError(path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def metric_reader(bench_dir: str, name: str) -> Callable:
+    """The `read` function of `<bench>/metrics/<name>.py`."""
+    return _module(bench_dir, "metrics", name).read
+
+
+def reference(cell: Cell) -> Callable:
+    """The `results` function of the cell's plain reference."""
+    name = cell.config.get("reference", DEFAULT_REFERENCE)
+    return _module(cell.bench_dir, "references", name).results
 
 
 def read_metrics(cell: Cell, run) -> Dict[str, dict]:

@@ -15,7 +15,6 @@ import shutil
 import sys
 import threading
 import time
-from concurrent.futures import ThreadPoolExecutor
 from typing import Dict, List, Optional
 
 import numpy as np
@@ -28,6 +27,30 @@ DISPATCH_SPAN = "sweep.dispatch"
 
 def log(msg: str) -> None:
     print(msg, file=sys.stderr, flush=True)
+
+
+def point_fields(config: dict) -> dict:
+    """Keyword arguments that every sweep point of the configuration's
+    cells gets: its `point` group, `SweepPoint`'s own fields by name. A
+    nested group becomes the spec class of its field (`hostcache` a
+    `HostCacheSpec`, `endurance` an `EnduranceSpec`); the dataclasses
+    refuse a field they do not have."""
+    import typing
+    from repro.core.ssd.endurance.spec import EnduranceSpec
+    from repro.hostcache.spec import HostCacheSpec
+    from repro.sweep.grid import SweepPoint
+    hints = typing.get_type_hints(SweepPoint, localns={
+        "EnduranceSpec": EnduranceSpec, "HostCacheSpec": HostCacheSpec})
+    out = {}
+    for name, value in config.get("point", {}).items():
+        if isinstance(value, dict):
+            specs = [t for t in typing.get_args(hints.get(name))
+                     if dataclasses.is_dataclass(t)]
+            if not specs:
+                raise TypeError(f"point field {name!r} is no spec group")
+            value = specs[0](**value)
+        out[name] = value
+    return out
 
 
 class Program:
@@ -54,10 +77,12 @@ class Program:
             idle_threshold_ms=d["idle_threshold_ms"],
             timing=TimingConfig(**cell.config["timing_ms"]))
         self.max_ops = cell.traffic.get("max_ops")
+        self.fields = point_fields(cell.config)
+        self.points(cell.traffic, [0])      # a field it has not, at load
 
     def points(self, traffic: dict, seeds: List[int]) -> list:
         return [self._point(trace=t, mode=traffic["mode"], policy=p,
-                            seed=s)
+                            seed=s, **self.fields)
                 for p in traffic["policies"] for t in traffic["traces"]
                 for s in seeds]
 
@@ -87,13 +112,6 @@ def iteration_seeds(traffic: dict, base: int, i: int) -> List[int]:
     return [base + k * i + j for j in range(k)]
 
 
-def truncated(tr: dict, max_ops: Optional[int]) -> dict:
-    if max_ops is None:
-        return tr
-    return {k: (v[:max_ops] if isinstance(v, np.ndarray) else v)
-            for k, v in tr.items()} | {"n_ops": min(tr["n_ops"], max_ops)}
-
-
 class RecipeMismatch(RuntimeError):
     """The program builds a named trace from another recipe than the
     traffic file's."""
@@ -102,7 +120,8 @@ class RecipeMismatch(RuntimeError):
 def recipe_check(prog: Program, traffic: dict, drive: reference.Drive,
                  seed: int) -> None:
     """Each trace the program builds equals the benchmark's own build
-    from the traffic file's recipe, array for array."""
+    from the traffic file's recipe, array for array; an `msr` trace's
+    published stats equal the recipe's besides."""
     for name, recipe in traffic["traces"].items():
         if recipe["kind"] == "msr":
             have = prog.trace_stats(name)
@@ -159,6 +178,30 @@ class CompileMeter:
         return self.compiles, self.compile_s, self.traces
 
 
+SPLIT_SPANS = ("sweep.group", "sweep.dispatch", "sweep.block",
+               "device.scan", "device.tail")
+
+
+def iteration_split(win: window.Window, spans: List[dict],
+                    tracer_t0: float) -> List[str]:
+    """One line per iteration of the window: its wall, the summed time
+    of the runner's spans that start inside it, and each fleet's scan."""
+    out = []
+    for it in win.iterations:
+        a, b = it.t0 - tracer_t0, it.t1 - tracer_t0
+        inside = [sp for sp in spans if a <= sp["t0_s"] < b]
+        tot = dict.fromkeys(SPLIT_SPANS, 0.0)
+        for sp in inside:
+            if sp["name"] in tot:
+                tot[sp["name"]] += sp["dur_s"]
+        scans = [f"{sp['dur_s']:.3f}" for sp in inside
+                 if sp["name"] == "device.scan"]
+        out.append(f"iteration {it.index}: {b - a:.3f} s; " + " ".join(
+            f"{k} {v:.3f}" for k, v in tot.items())
+            + f"; fleet scans {' '.join(scans)}")
+    return out
+
+
 @dataclasses.dataclass
 class Run:
     """What the per-layer readers read (`bench/metrics/*.py`)."""
@@ -183,36 +226,12 @@ def sample_cells(win: window.Window, seed: int, n: int):
     return pts, [it.results[p] for p in pts]
 
 
-def reference_results(cell: catalog.Cell, drive: reference.Drive,
-                      points: list, ftype: str = "float32",
-                      threads: int = 4, device=None) -> List[Dict]:
-    """The plain reference's summaries of `points`, grouped by policy and
-    mode, groups run side by side on host threads."""
-    traffic = cell.traffic
-    groups: Dict[tuple, list] = {}
-    for i, p in enumerate(points):
-        groups.setdefault((p.policy, p.mode), []).append(i)
-
-    def one(key):
-        policy, mode = key
-        idx = groups[key]
-        traces, wastes = [], []
-        for i in idx:
-            p = points[i]
-            recipe = traffic["traces"][p.trace]
-            traces.append(truncated(synth.build(
-                p.trace, recipe, drive.n_logical, drive.total_pages, mode,
-                p.seed), traffic.get("max_ops")))
-            wastes.append(reference.agc_waste(recipe["stats"]))
-        return idx, reference.simulate(drive, policy, mode, traces,
-                                       wastes, ftype, device)
-
-    out: List[Optional[Dict]] = [None] * len(points)
-    with ThreadPoolExecutor(max(1, min(threads, len(groups)))) as ex:
-        for idx, summ in ex.map(one, sorted(groups)):
-            for i, s in zip(idx, summ):
-                out[i] = s
-    return out
+def reference_results(cell: catalog.Cell, points: list,
+                      ftype: str = "float32", device=None) -> List[Dict]:
+    """The summaries of `points` by the cell's plain reference
+    (`catalog.reference`)."""
+    return catalog.reference(cell)(cell.config, cell.traffic, points,
+                                   ftype, device)
 
 
 def memory_peak() -> int:
@@ -380,6 +399,7 @@ def run_cell(cell: catalog.Cell, *, seed: int, seconds: float, trace: bool,
         f" jaxpr traces; persistent cache {meter.hits} hits, "
         f"{meter.misses} misses")
 
+    tracer_t0 = time.perf_counter()
     tracer = prog.Tracer()
 
     setup_s = time.perf_counter() - t_start
@@ -392,6 +412,8 @@ def run_cell(cell: catalog.Cell, *, seed: int, seconds: float, trace: bool,
     log(f"window: {len(win.iterations)} iterations, {win.seconds:.3f} s, "
         f"{win.live_ops} live ops; {w1[0] - w0[0]} backend compiles and "
         f"{w1[2] - w0[2]} jaxpr traces inside it")
+    for line in iteration_split(win, tracer.spans, tracer_t0):
+        log(line)
     peak = memory_peak()
     dev = dict(device or device_info())
     dev["memory_peak_bytes"] = peak
@@ -402,7 +424,7 @@ def run_cell(cell: catalog.Cell, *, seed: int, seconds: float, trace: bool,
     # the comparison, after the window and the memory reading
     t_ref = time.perf_counter()
     pts, got = sample_cells(win, seed, int(traffic["reference_cells"]))
-    want = reference_results(cell, drive, pts)
+    want = reference_results(cell, pts)
     cmp = check.compare(got, want, [p.key for p in pts])
     readings = {"cells_missing": win.missing,
                 "counter_mismatch": cmp.counter_mismatch,
@@ -452,8 +474,13 @@ def main(argv=None, *, t_start: float) -> int:
     args = ap.parse_args(argv)
     cell = catalog.load_cell(args.workload)
 
+    t_args = time.perf_counter()
     import jax
+    t_jax = time.perf_counter()
     dev = device_info()
+    log(f"setup split: start_s {t_args - t_start:.3f} import_jax_s "
+        f"{t_jax - t_args:.3f} backend_init_s "
+        f"{time.perf_counter() - t_jax:.3f}")
     if dev["platform"] != "tpu" or dev["count"] != cell.chips:
         log(f"bench: JAX found {dev['count']} {dev['platform']} device(s) "
             f"({dev['kind']}); cell {cell.name} needs {cell.chips} TPU "

@@ -11,6 +11,12 @@ program builds bit-identical tensors.
 A recipe is a dict from a traffic file:
 
   {"kind": "msr", "stats": {...TraceStats fields...}}
+  {"kind": "phases", "label": "...", "cycles": n,
+   "phases": [{...TraceStats fields...}, ...]}
+
+A `phases` recipe is the program's phase synthesizer: the `phases` list
+repeated `cycles` times, phase `i` drawn with the label `{label}.{i}`,
+each phase starting 1 ms after the previous one's last arrival.
 
 `build(name, recipe, n_logical, capacity_pages, mode, seed)` returns the
 padded op dict (arrival_ms f32, lba i32, is_write i8, n_ops).
@@ -18,6 +24,7 @@ padded op dict (arrival_ms f32, lba i32, is_write i8, n_ops).
 from __future__ import annotations
 
 import zlib
+from typing import Optional
 
 import numpy as np
 
@@ -70,6 +77,24 @@ def requests(stats: dict, n_logical: int, seed: int, capacity_pages: int,
             "is_write": is_write}
 
 
+def phases(recipe: dict, n_logical: int, seed: int,
+           capacity_pages: int) -> dict:
+    """Request-level trace of a `phases` recipe: its phases tiled along
+    the arrival axis."""
+    seq = list(recipe["phases"]) * int(recipe["cycles"])
+    if not seq:
+        raise ValueError("a phases recipe needs at least one phase")
+    parts, offset = [], 0.0
+    for i, stats in enumerate(seq):
+        req = requests(stats, n_logical, seed, capacity_pages,
+                       label=f"{recipe['label']}.{i}")
+        arrival = req["arrival_ms"] + offset
+        if len(arrival):
+            offset = float(arrival[-1]) + 1.0
+        parts.append(req | {"arrival_ms": arrival})
+    return {k: np.concatenate([p[k] for p in parts]) for k in parts[0]}
+
+
 def bursty(req: dict, n_logical: int) -> dict:
     """The write volume as back-to-back sequential 32 KB writes."""
     total = int(np.asarray(req["pages"])[
@@ -102,12 +127,22 @@ def expand(req: dict, n_logical: int) -> dict:
             "n_ops": o}
 
 
+def truncated(tr: dict, max_ops: Optional[int]) -> dict:
+    """The first `max_ops` ops of a padded op dict (all of it for None)."""
+    if max_ops is None:
+        return tr
+    return {k: (v[:max_ops] if isinstance(v, np.ndarray) else v)
+            for k, v in tr.items()} | {"n_ops": min(tr["n_ops"], max_ops)}
+
+
 def build(name: str, recipe: dict, n_logical: int, capacity_pages: int,
           mode: str, seed: int) -> dict:
     """Padded op tensors of one named trace under `mode` and `seed`."""
     if recipe["kind"] == "msr":
         req = requests(recipe["stats"], n_logical, seed, capacity_pages,
                        label=name)
+    elif recipe["kind"] == "phases":
+        req = phases(recipe, n_logical, seed, capacity_pages)
     else:
         raise ValueError(f"trace {name}: unknown recipe kind "
                          f"{recipe['kind']!r}")

@@ -26,11 +26,11 @@ os.makedirs(CACHE, exist_ok=True)
 os.environ["JAX_COMPILATION_CACHE_DIR"] = CACHE
 sys.path[:0] = [BENCH, os.path.join(ROOT, "src")]
 
-from benchlib import catalog, cell, check, reference  # noqa: E402
+from benchlib import catalog, cell, check  # noqa: E402
 
 
-def readings(c, drive, pts, got) -> dict:
-    want = cell.reference_results(c, drive, pts)
+def readings(c, pts, got) -> dict:
+    want = cell.reference_results(c, pts)
     cmp = check.compare(got, want, [p.key for p in pts])
     return {"counter_mismatch": cmp.counter_mismatch,
             "float_rel_gap": cmp.float_rel_gap, "worst": cmp.worst,
@@ -47,7 +47,6 @@ def main() -> int:
     c = catalog.load_cell(args.workload)
     from repro import compile_cache
     compile_cache.enable()
-    drive = reference.drive_of(c.config)
     prog = cell.Program(c)
     n = int(c.traffic["reference_cells"])
     prog_r, ctrl_r = [], []
@@ -60,7 +59,7 @@ def main() -> int:
         win = cell.window.Window(t0, t1, [cell.window.Iteration(
             0, t0, t1, pts, res, [])])
         spts, got = cell.sample_cells(win, seed, n)
-        r = readings(c, drive, spts, got) | {
+        r = readings(c, spts, got) | {
             "seed": seed, "side": "program", "missing": win.missing,
             "sweep_s": t1 - t0, "ref_s": time.perf_counter() - t1}
         prog_r.append(r)
@@ -74,9 +73,9 @@ def main() -> int:
         t0 = time.perf_counter()
         # on the chip: bfloat16 is emulated, and slow, on the host's CPU
         import jax
-        got = cell.reference_results(c, drive, spts, ftype="bfloat16",
+        got = cell.reference_results(c, spts, ftype="bfloat16",
                                      device=jax.devices()[0])
-        r = readings(c, drive, spts, got) | {
+        r = readings(c, spts, got) | {
             "seed": seed, "side": "control", "s": time.perf_counter() - t0}
         ctrl_r.append(r)
         print(json.dumps(r), flush=True)

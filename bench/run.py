@@ -2,8 +2,9 @@
 
     python bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
-The cell, its configuration, its traffic and its metrics are found by
-name from `BENCHMARK.json` (`bench/benchlib/catalog.py`). The run needs
+The cell, its configuration, its traffic, its metrics and its plain
+reference are found by name from `BENCHMARK.json`
+(`bench/benchlib/catalog.py`). The run needs
 the chips the cell asks for: without them it exits 2 and prints no
 result. JAX's compilation cache is kept in `.jax_cache/` at the root of
 the checkout, so only a checkout's first run of a cell compiles.

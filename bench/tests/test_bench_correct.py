@@ -17,7 +17,7 @@ import time
 import benchtest
 import pytest
 
-from benchlib import cell, reference
+from benchlib import cell
 
 SEED = 2 ** 31 + 3
 
@@ -64,9 +64,7 @@ def one_counter_altered(prog, points, res):
 
 
 def control_in_place(prog, points, res):
-    got = cell.reference_results(
-        prog.cell, reference.drive_of(prog.cell.config), points,
-        ftype="bfloat16")
+    got = cell.reference_results(prog.cell, points, ftype="bfloat16")
     return dict(zip(points, got))
 
 

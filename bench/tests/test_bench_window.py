@@ -78,6 +78,24 @@ def test_device_readers_find_nothing_without_a_trace():
         assert catalog.metric_reader(catalog.BENCH_DIR, name)(run) is None
 
 
+def test_iteration_split_puts_each_span_in_the_iteration_it_starts_in():
+    win = fake_run([2.0, 3.0], [5, 6], seconds=4.0)     # 100-102, 102-105
+    t0 = 99.0                    # the tracer's clock starts 1 s earlier
+    spans = [{"name": n, "t0_s": a, "dur_s": d} for n, a, d in [
+        ("sweep.group", 1.0, 0.5), ("device.scan", 1.5, 0.25),
+        ("device.scan", 2.0, 0.5), ("device.tail", 2.9, 0.25),
+        ("sweep.group", 3.0, 1.0), ("device.scan", 4.0, 1.5),
+        ("workload", 3.5, 0.5)]]
+    lines = cell.iteration_split(win, spans, t0)
+    assert lines == [
+        "iteration 0: 2.000 s; sweep.group 0.500 sweep.dispatch 0.000 "
+        "sweep.block 0.000 device.scan 0.750 device.tail 0.250; "
+        "fleet scans 0.250 0.500",
+        "iteration 1: 3.000 s; sweep.group 1.000 sweep.dispatch 0.000 "
+        "sweep.block 0.000 device.scan 1.500 device.tail 0.000; "
+        "fleet scans 1.500"]
+
+
 def test_iteration_seeds():
     assert cell.iteration_seeds({}, 10, 2) == [12]
     assert cell.iteration_seeds({"seeds_per_iteration": 8}, 10, 1) == \

@@ -104,6 +104,9 @@ def build_tier_step(cfg, policy, hc_spec: HostCacheSpec, *,
         if promote == "always":
             promote_ok = live
             shadow_tag_new, shadow_cnt_new = hc.shadow_tag, hc.shadow_cnt
+        elif promote == "write":            # write misses allocate, read
+            promote_ok = is_write           # misses go to the device alone
+            shadow_tag_new, shadow_cnt_new = hc.shadow_tag, hc.shadow_cnt
         else:
             sh_match = hc.shadow_tag[si] == lba
             cnt = jnp.where(sh_match, hc.shadow_cnt[si] + 1, jnp.int32(1))

@@ -22,7 +22,7 @@ from dataclasses import dataclass, fields, replace
 __all__ = ["HostCacheSpec", "MODES", "PROMOTES", "FLUSHES"]
 
 MODES = ("wb", "wt", "wa")          # write-back / write-through / write-around
-PROMOTES = ("always", "nth")
+PROMOTES = ("always", "nth", "write")   # "write": write misses only
 FLUSHES = ("watermark", "idle")
 
 
@@ -49,6 +49,10 @@ class HostCacheSpec:
         if self.promote not in PROMOTES:
             raise ValueError(
                 f"hostcache promote {self.promote!r} not in {PROMOTES}")
+        if self.promote == "write" and self.mode == "wa":
+            # write-around allocates on read misses alone: a write-only
+            # gate would leave it nothing to allocate
+            raise ValueError("hostcache promote='write' needs mode wb or wt")
         if self.flush not in FLUSHES:
             raise ValueError(
                 f"hostcache flush {self.flush!r} not in {FLUSHES}")
@@ -94,6 +98,8 @@ class HostCacheSpec:
         parts = [self.mode, self.flush]
         if self.promote == "nth":
             parts.append(f"p{self.promote_n:g}")
+        elif self.promote == "write":
+            parts.append("pw")
         if (self.sets, self.ways) != (128, 8):
             parts.append(f"{self.sets}x{self.ways}")
         if (self.wm_hi, self.wm_lo) != (0.75, 0.5):

@@ -238,11 +238,13 @@ def run_sweep(cfg: SSDConfig, points: Sequence[SweepPoint], *,
     `sweep.group`, `sweep.dispatch` and `sweep.block`, each with the
     `jaxpr_traces` and `backend_compiles` made inside it; each fleet's
     `device.scan` and `device.tail`, stamped by a completion watcher
-    thread (DESIGN.md §13). `timings` keeps working without a tracer,
-    and then no thread starts. Each timings row also carries
-    `compiles`: how many fresh fleet compilations that group's dispatch
-    triggered, `devices`: how many devices its stacked cells were laid
-    over, plus the group's
+    thread (DESIGN.md §13). A fleet's `sweep.dispatch` and `device.scan`
+    name its host tier (`hostcache`, the spec's tag or None) and the
+    device sub-op slots a scanned step runs (`sub_ops`; DESIGN.md §14).
+    `timings` keeps working without a tracer, and then no thread starts.
+    Each timings row also carries `compiles`: how many fresh fleet
+    compilations that group's dispatch triggered, `devices`: how many
+    devices its stacked cells were laid over, plus the group's
     throughput (`ops_per_s` over the padded length, `cells_per_s`) and
     which raw-speed knobs applied (`t_scan`, `packed`).
 
@@ -357,7 +359,7 @@ def run_sweep(cfg: SSDConfig, points: Sequence[SweepPoint], *,
                       group=grp["names"], mode=grp["mode"],
                       cells=len(grp["pts"]), pad=grp["pad"],
                       t_scan=grp["t_scan"], t_len=grp["t_len"],
-                      exec_path=grp["exec_path"])
+                      exec_path=grp["exec_path"], **grp["tier"])
         tracer.record("device.tail", "device", t_scan, t_summ,
                       group=grp["names"], mode=grp["mode"])
 
@@ -422,9 +424,13 @@ def run_sweep(cfg: SSDConfig, points: Sequence[SweepPoint], *,
                 progress(f"fleet {names}/{mode}: {n_cells} cells"
                          f"{f' (+{pad} pad)' if pad else ''} x {_t_len} ops"
                          f" on {n_dev} device(s)")
+            # the host tier, if any: its spec's tag, and the device sub-op
+            # slots each scanned step runs (DESIGN.md §14)
+            tier = {"hostcache": None if _hc is None else _hc.tag,
+                    "sub_ops": 1 if _hc is None else 2 + _hc.flush_per_op}
             c0 = fleet.compile_count()
             with _phase("sweep.dispatch", counts, group=names, mode=mode,
-                        cells=n_cells, t_len=_t_len) as rec:
+                        cells=n_cells, t_len=_t_len, **tier) as rec:
                 ops = fleet.shard_cells(fleet.stack_ops(traces))
                 stacked = fleet.shard_cells(fleet.stack_params(params))
                 t_scan = (fleet._trim_len(np.asarray(ops["is_write"]))
@@ -447,6 +453,7 @@ def run_sweep(cfg: SSDConfig, points: Sequence[SweepPoint], *,
                             "summ": summ, "names": names, "mode": mode,
                             "spec": spec, "t_len": _t_len, "pad": pad,
                             "t_scan": t_scan, "packed": pack_grp,
+                            "tier": tier,
                             "devices": len(ops["lba"].sharding.device_set),
                             "exec_path": ("segment" if t_scan < _t_len
                                           else "per_op"),

@@ -111,7 +111,8 @@ def test_paper_trim_fleet_compiles(topo, no_persistent_cache, policy, mode,
     _fits_one_chip(_compile_trim(point, 11, t_scan, one))
 
 
-@pytest.mark.parametrize("group", ["endurance", "hostcache", "telemetry"])
+@pytest.mark.parametrize("group", ["endurance", "hostcache",
+                                   "hostcache-wb", "telemetry"])
 def test_per_op_fleet_compiles(topo, no_persistent_cache, group):
     one = SingleDeviceSharding(topo.devices[0])
     timeline = None
@@ -126,6 +127,15 @@ def test_per_op_fleet_compiles(topo, no_persistent_cache, group):
                            hostcache=HostCacheSpec(mode="wb",
                                                    flush="watermark"))
         n_cells = 1
+    elif group == "hostcache-wb":
+        # the benchmark cell's tier and fleet: dm-writecache's write-only
+        # allocation and watermarks, 8 cells
+        point = SweepPoint(trace="flush_burst", mode="daily",
+                           policy="coop",
+                           hostcache=HostCacheSpec(mode="wb",
+                                                   promote="write",
+                                                   wm_hi=0.5, wm_lo=0.45))
+        n_cells = 8
     else:
         point = SweepPoint(trace="hm_0", mode="daily", policy="ips")
         n_cells, timeline = 8, 1024

@@ -107,6 +107,7 @@ class TestConservation:
     @pytest.mark.parametrize("hc", [
         HostCacheSpec(mode="wb", flush="watermark"),
         HostCacheSpec(mode="wb", flush="idle"),
+        HostCacheSpec(mode="wb", promote="write"),
         HostCacheSpec(mode="wt"),
         HostCacheSpec(mode="wa"),
     ], ids=lambda hc: hc.tag)
@@ -232,6 +233,49 @@ class TestWriteBackAbsorption:
             hits[promote] = _hctr(st)[H_CTR["hits"]]
         assert hits["nth"] <= hits["always"]
 
+    @pytest.mark.parametrize("promote,want", [
+        # the read miss allocates; the read and the write after it hit
+        ("always", dict(hits=3, read_hits=2, write_hits=1, absorbed=3,
+                        absorbed_w=1, dev_ops=1)),
+        # reads miss twice and allocate nothing; the write miss allocates,
+        # and the read after it hits
+        ("write", dict(hits=1, read_hits=1, write_hits=0, absorbed=2,
+                       absorbed_w=1, dev_ops=2))])
+    def test_write_promotion_allocates_on_write_misses_only(self, promote,
+                                                             want):
+        """promote=write: a read miss goes to the device and leaves its
+        set as it was; a write miss allocates (dirty, absorbed); a read
+        hit is served from the tier."""
+        lba = 4242
+        trace = {"arrival_ms": np.arange(4, dtype=np.float32),
+                 "lba": np.full(4, lba, np.int32),
+                 "is_write": np.array([0, 0, 1, 0], np.int8)}
+        _, st = _run_hc("ips", trace, "daily",
+                        HostCacheSpec(promote=promote))
+        h = _hctr(st)
+        assert {k: h[H_CTR[k]] for k in want} == want
+        assert h[H_CTR["absorbed"]] + h[H_CTR["dev_ops"]] == 4
+        tags = np.asarray(st.hostcache.tag)
+        assert (tags == lba).sum() == 1        # resident once, dirty
+        assert np.asarray(st.hostcache.dirty)[tags == lba].tolist() == [1]
+        # the device saw the trace ops the tier did not absorb, and no
+        # write: the written line is still held dirty
+        assert float(np.asarray(st.counters)[CTR["host_w"]]) == 0.0
+
+    def test_write_promotion_keeps_reads_out_of_the_tier(self):
+        """On a read-only prefix nothing is ever allocated: every read
+        misses, goes to the device and leaves every set empty."""
+        trace = _hm0("daily", max_ops=8192)
+        isw = np.asarray(trace["is_write"]).copy()
+        isw[isw == 1] = 0
+        trace = dict(trace, is_write=isw)
+        _, st = _run_hc("ips", trace, "daily",
+                        HostCacheSpec(promote="write"))
+        h = _hctr(st)
+        assert h[H_CTR["hits"]] == 0 and h[H_CTR["absorbed"]] == 0
+        assert h[H_CTR["dev_ops"]] == int((isw >= 0).sum())
+        assert (np.asarray(st.hostcache.tag) == -1).all()
+
 
 class TestFlushBurstCliff:
     """The ISSUE acceptance: the telemetry cliff detector surfaces a
@@ -263,8 +307,11 @@ class TestFlushBurstCliff:
 
 
 class TestFleetEquivalence:
-    def test_fleet_matches_single_cell_with_hostcache(self):
-        hc = HostCacheSpec(mode="wb", flush="watermark")
+    @pytest.mark.parametrize("hc", [
+        HostCacheSpec(mode="wb", flush="watermark"),
+        HostCacheSpec(mode="wb", promote="write", flush="watermark",
+                      wm_hi=0.5, wm_lo=0.45)], ids=lambda hc: hc.tag)
+    def test_fleet_matches_single_cell_with_hostcache(self, hc):
         traces = [_hm0("daily", 8192),
                   truncate_trace(
                       make_trace("hm_1", N_LOGICAL, mode="daily",
@@ -291,13 +338,26 @@ class TestFleetEquivalence:
 
 
 class TestSpecAndGrid:
-    def test_parse_round_trip_and_tag(self):
-        hc = HostCacheSpec.parse("mode=wt,sets=64,ways=4,wm_hi=0.9")
-        assert hc.mode == "wt" and hc.sets == 64 and hc.ways == 4
-        assert hc.wm_hi == 0.9 and hc.lines == 256
-        assert hc.tag == "wt:watermark:64x4:wm0.9-0.5"
+    @pytest.mark.parametrize("text,fields,tag", [
+        ("mode=wt,sets=64,ways=4,wm_hi=0.9",
+         dict(mode="wt", sets=64, ways=4, wm_hi=0.9),
+         "wt:watermark:64x4:wm0.9-0.5"),
+        ("promote=write,wm_hi=0.5,wm_lo=0.45",
+         dict(promote="write", wm_hi=0.5, wm_lo=0.45),
+         "wb:watermark:pw:wm0.5-0.45"),
+        ("promote=nth,promote_n=3", dict(promote="nth", promote_n=3.0),
+         "wb:watermark:p3")])
+    def test_parse_round_trip_and_tag(self, text, fields, tag):
+        hc = HostCacheSpec.parse(text)
+        assert hc == HostCacheSpec(**fields)
+        assert hc.tag == tag
+        assert HostCacheSpec.parse("sets=64,ways=4").lines == 256
         assert HostCacheSpec.parse("") == HostCacheSpec()
         assert HostCacheSpec().tag == "wb:watermark"
+        # the tags of the three promote gates differ: the point key
+        # tells them apart
+        assert len({HostCacheSpec(promote=p).tag
+                    for p in ("always", "nth", "write")}) == 3
 
     @pytest.mark.parametrize("text", [
         "nope=1", "mode=magic", "sets=abc", "mode", "flush=never"])
@@ -310,6 +370,8 @@ class TestSpecAndGrid:
             HostCacheSpec(sets=2, flush_per_op=2)
         with pytest.raises(ValueError, match="off == omit"):
             HostCacheSpec(mode="off")
+        with pytest.raises(ValueError, match="promote='write'"):
+            HostCacheSpec(mode="wa", promote="write")
 
     def test_point_key_carries_hostcache_tag(self):
         hc = HostCacheSpec(mode="wb", flush="idle")

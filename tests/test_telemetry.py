@@ -419,6 +419,36 @@ class TestSweepDeviceSpans:
         assert all(s["args"]["backend_compiles"] == 0 for s in again)
 
 
+    def test_tier_fleets_name_their_tier_and_sub_ops(self, sweeps):
+        """A tier fleet's `sweep.dispatch` and `device.scan` spans carry
+        its spec's tag and its device sub-op slots a step (2 +
+        flush_per_op); other fleets carry None and 1."""
+        from repro import workloads
+        from repro.hostcache import HostCacheSpec
+        from repro.sweep.grid import SweepPoint
+        from repro.sweep.runner import run_sweep
+        hc = HostCacheSpec(promote="write", flush_per_op=3)
+        pts = [SweepPoint(trace="hm_0", mode="daily", policy="ips",
+                          seed=3, hostcache=h) for h in (hc, None)]
+        tracer = Tracer()
+        with tracer.activate():
+            run_sweep(CFG, pts, max_ops=1024,
+                      trace_cache=workloads.TraceCache(use_disk=False))
+        got = [(s["name"], s["args"]["hostcache"], s["args"]["sub_ops"])
+               for s in tracer.spans
+               if s["name"] in ("sweep.dispatch", "device.scan")]
+        assert len(got) == 4
+        assert set(got) == {("device.scan", None, 1),
+                            ("device.scan", hc.tag, 5),
+                            ("sweep.dispatch", None, 1),
+                            ("sweep.dispatch", hc.tag, 5)}
+        # the paper fleets of the module's sweeps: no tier
+        for s in sweeps["first"].spans:
+            if s["name"] in ("sweep.dispatch", "device.scan"):
+                assert (s["args"]["hostcache"], s["args"]["sub_ops"]) == \
+                    (None, 1)
+
+
 class TestSegmentWindows:
     """Segment-aware telemetry (DESIGN.md §13): the compressed segment
     executor's boundary snapshots must re-expand into the SAME per-window

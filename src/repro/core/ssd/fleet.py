@@ -256,16 +256,53 @@ def _run_fleet_trim(cfg: SSDConfig, spec, state0: SimState, ops: dict,
     return jax.vmap(one)(state0, ops, params, pad_t)
 
 
+@functools.partial(jax.jit, static_argnames=("cfg", "spec", "closed_loop",
+                                             "n_pad", "hostcache"),
+                   donate_argnums=(2,))
+def _run_tier_trim(cfg: SSDConfig, spec, state0: SimState, ops: dict,
+                   params: CellParams, pad_t, *, closed_loop: bool,
+                   n_pad: int, hostcache):
+    """The trimmed scan of a host-tier fleet (DESIGN.md §14): `ops` hold
+    only the (C, T_trim) prefix, and the `n_pad` identical tail pads are
+    replayed to their exact fixed point.
+
+    A trace pad is a fixed point of the host tier: it is not live, so it
+    hits, inserts and flushes nothing, and moves no clock; its K device
+    sub-ops are all pads, and its latency is exactly 0.0. Only the
+    watermark latch may change on the first pad, where it re-reads the
+    dirty count the last live op's flush left. So the first pad runs as
+    a whole tier step, and the remaining K·(n_pad − 1) device sub-ops,
+    identical pads, go to `sim.replay_pads` on the reduced carry."""
+    from repro.hostcache.pipeline import build_tier_step
+    k = 2 + hostcache.flush_per_op
+
+    def one(cell_state, cell_ops, cell_params, cell_pad_t):
+        step = build_tier_step(cfg, spec, hostcache,
+                               closed_loop=closed_loop, params=cell_params)
+        core = _build_core(cfg, spec, closed_loop=closed_loop,
+                           params=cell_params)
+        final, latency = jax.lax.scan(step, cell_state, cell_ops)
+        final, _ = step(final, {"arrival_ms": cell_pad_t,
+                                "lba": jnp.int32(0),
+                                "is_write": jnp.int32(-1)})
+        red = replay_pads(core, reduced_of(final), final.loc[0],
+                          final.loc_ep[0], cell_pad_t, k * (n_pad - 1))
+        return latency, final._replace(**red._asdict())
+
+    return jax.vmap(one)(state0, ops, params, pad_t)
+
+
 def compile_count() -> int:
     """Fleet-scan compilations so far in this process: the sizes of the
-    `_run_fleet` and `_run_fleet_trim` jit caches, keyed on (cfg,
-    composition, mode) and the stacked (C, T) array shapes — including
-    the carry dtypes, so packed and unpacked fleets compile separately.
-    Traced-knob variation (CellParams values, endurance weights/budgets)
-    never grows it. The search engine (repro.search) records per-round
-    deltas of this in BENCH_search.json and asserts knob-only rounds add
-    zero."""
-    return _run_fleet._cache_size() + _run_fleet_trim._cache_size()
+    `_run_fleet`, `_run_fleet_trim` and `_run_tier_trim` jit caches,
+    keyed on (cfg, composition, mode) and the stacked (C, T) array
+    shapes — including the carry dtypes, so packed and unpacked fleets
+    compile separately. Traced-knob variation (CellParams values,
+    endurance weights/budgets) never grows it. The search engine
+    (repro.search) records per-round deltas of this in BENCH_search.json
+    and asserts knob-only rounds add zero."""
+    return (_run_fleet._cache_size() + _run_fleet_trim._cache_size()
+            + _run_tier_trim._cache_size())
 
 
 def _trim_len(is_write: np.ndarray, quantum: int = TRIM_QUANTUM) -> int:
@@ -306,26 +343,34 @@ def run_fleet(cfg: SSDConfig, policy, ops: dict, params: CellParams,
     bit-identical either way (tests/test_compress.py).
 
     `hostcache` (static: a `HostCacheSpec`, or None) stacks the host
-    block-cache tier in front of every cell (DESIGN.md §14); such fleets
-    take the full per-op path (the tier pipeline rewrites the device op
-    stream in-scan, so there is no trimmed/compressed shortcut)."""
+    block-cache tier in front of every cell (DESIGN.md §14). `trim_pads`
+    trims such a fleet too (`_run_tier_trim`), unless its telemetry
+    probe is on: the host windows need a per-op row over the whole
+    length (tests/test_hostcache.py)."""
     spec = resolve_spec(policy)
     n_cells = ops["lba"].shape[0]
     endurance = params.endurance is not None
-    if trim_pads and not endurance and hostcache is None:
+    if (trim_pads and not endurance
+            and (hostcache is None or timeline_ops is None)):
         is_w = np.asarray(ops["is_write"])
         t_len = is_w.shape[1]
         t_trim = _trim_len(is_w)
         if t_trim < t_len:
             state0 = shard_cells(init_fleet_state(
                 cfg, n_logical, n_cells, timeline=timeline_ops,
-                packed=packed))
+                packed=packed, hostcache=hostcache))
             ops_trim = {k: v[:, :t_trim] for k, v in ops.items()}
             pad_t = jnp.asarray(ops["arrival_ms"][:, t_trim], jnp.float32)
-            latency, final = _run_fleet_trim(
-                cfg, spec, state0, ops_trim, params, pad_t,
-                closed_loop=closed_loop, n_pad=t_len - t_trim,
-                timeline_ops=timeline_ops)
+            if hostcache is None:
+                latency, final = _run_fleet_trim(
+                    cfg, spec, state0, ops_trim, params, pad_t,
+                    closed_loop=closed_loop, n_pad=t_len - t_trim,
+                    timeline_ops=timeline_ops)
+            else:
+                latency, final = _run_tier_trim(
+                    cfg, spec, state0, ops_trim, params, pad_t,
+                    closed_loop=closed_loop, n_pad=t_len - t_trim,
+                    hostcache=hostcache)
             latency = jnp.pad(latency, ((0, 0), (0, t_len - t_trim)))
             return latency, final
     state0 = shard_cells(init_fleet_state(

@@ -251,9 +251,11 @@ def run_sweep(cfg: SSDConfig, points: Sequence[SweepPoint], *,
     Raw-speed defaults (DESIGN.md §12): `trim_pads=True` scans only each
     group's shared live prefix and replays the identical all-pad tail to
     its exact fixed point — telemetry groups stay on it (segment-aware
-    windows, DESIGN.md §13); endurance groups automatically take the
-    full path (a one-line warning marks the fallback when a timeline was
-    requested, and each timings row records which `exec_path` ran);
+    windows, DESIGN.md §13), and host-tier groups without one
+    (DESIGN.md §14); endurance groups and host-tier groups with a
+    timeline take the full path (a one-line warning marks the endurance
+    fallback when a timeline was requested, and each timings row records
+    which `exec_path` ran);
     `packed="auto"` carries int16 plane fields
     whenever every cell's caps provably fit (`policies.state.can_pack`),
     `True`/`False` force it. Results are bit-identical either way —
@@ -407,10 +409,12 @@ def run_sweep(cfg: SSDConfig, points: Sequence[SweepPoint], *,
             pack_grp = (packed if isinstance(packed, bool)
                         else all(can_pack(cfg, n_logical, p) for p in params))
             if _hc is not None:
-                # the tier pipeline rewrites ops in-scan (K sub-op slots per
-                # trace op) — no trimmed/packed fast path (DESIGN.md §14)
+                # the tier pipeline runs unpacked; it trims its pad tail
+                # unless the probe is on, whose host windows need a per-op
+                # row to the end (DESIGN.md §14)
                 pack_grp = False
-            trim_grp = (trim_pads and not _endur and _hc is None)
+            trim_grp = (trim_pads and not _endur
+                        and (_hc is None or timeline_ops is None))
             if timeline_ops is not None and trim_pads and _endur:
                 # the fallback used to be silent — a fleet that quietly
                 # forfeits the fast path just looks "slow" (DESIGN.md §13)

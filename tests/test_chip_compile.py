@@ -148,6 +148,23 @@ def test_per_op_fleet_compiles(topo, no_persistent_cache, group):
     _fits_one_chip(compiled)
 
 
+def test_tier_trim_fleet_compiles(topo, no_persistent_cache):
+    """The benchmark cell's trimmed tier fleet: dm-writecache's tier in
+    front of coop, 8 cells, the flush_burst live prefix as the runner
+    trims it (57,344 of 2^17 steps), the rest replayed."""
+    one = SingleDeviceSharding(topo.devices[0])
+    hc = HostCacheSpec(mode="wb", promote="write", wm_hi=0.5, wm_lo=0.45)
+    point = SweepPoint(trace="flush_burst", mode="daily", policy="coop",
+                       hostcache=hc)
+    n_cells, t_scan = 8, 57344
+    state0, ops, params = _fleet_args(point, n_cells, t_scan, one)
+    pad_t = jax.ShapeDtypeStruct((n_cells,), jnp.float32, sharding=one)
+    compiled = fleet._run_tier_trim.lower(
+        CFG, get_spec(point.policy), state0, ops, params, pad_t,
+        closed_loop=False, n_pad=PAD_OPS - t_scan, hostcache=hc).compile()
+    _fits_one_chip(compiled)
+
+
 def _computations(hlo: str) -> dict:
     """{computation name: its instruction lines} of an HLO module text."""
     comps, name = {}, None

@@ -18,13 +18,20 @@ Load-bearing contracts:
   is visible on the device-visible latency series: the baseline policy
   cliffs early on the bursty flush_burst scenario and IPS shrinks it.
 * Fleet/single-cell equivalence extends to the host-cache state.
+* Pad-tail trimming is exact for tier fleets: the trimmed scan plus the
+  tail replay equals the full-length scan on every leaf and every
+  latency, and the sweep runner routes a tier group to it exactly when
+  it has a pad tail and neither a timeline nor wear tracking.
 
 Satellite coverage rides along: HostCacheSpec parse/tag validation, the
 `hostcache` sweep grid, and report-layer pairing (`hostcache_summary`,
 headline geomeans excluding host-tier cells).
 """
+import os
+
 import numpy as np
 import pytest
+import jax
 import jax.numpy as jnp
 
 from golden_sim import golden_run_trace
@@ -39,6 +46,7 @@ from repro.hostcache.model import H_CTR, HostWindows, as_hc_params
 from repro.sweep.grid import SweepPoint, hostcache_grid, named_grid
 from repro.sweep.report import hostcache_summary, policy_geomeans
 from repro.telemetry.timeline import detect_cliff
+from repro.workloads.compress import TRIM_QUANTUM
 from repro.workloads.generators import flush_burst
 
 CFG = PAPER_SSD.scaled(128)
@@ -335,6 +343,177 @@ class TestFleetEquivalence:
                     np.asarray(val),
                     np.asarray(getattr(st_f.hostcache, f)[i])), \
                     f"hostcache.{f} mismatch cell {i}"
+
+
+def _with_tail(trace, n_live, t_len):
+    """The first `n_live` ops of `trace`, then `ir.pad_ops`-contract pads
+    (the last arrival, lba 0, is_write -1) up to `t_len`."""
+    n_pad = t_len - n_live
+    return {
+        "arrival_ms": np.concatenate([
+            np.asarray(trace["arrival_ms"][:n_live], np.float32),
+            np.full(n_pad, trace["arrival_ms"][n_live - 1], np.float32)]),
+        "lba": np.concatenate([np.asarray(trace["lba"][:n_live], np.int32),
+                               np.zeros(n_pad, np.int32)]),
+        "is_write": np.concatenate([
+            np.asarray(trace["is_write"][:n_live], np.int32),
+            np.full(n_pad, -1, np.int32)])}
+
+
+def _tier_fleet(policy, traces, mode, hc, *, trim_pads):
+    params = [default_params(CFG, policy)._replace(
+        hostcache=as_hc_params(hc))] * len(traces)
+    return fleet.run_fleet(
+        CFG, policy, fleet.stack_ops(traces), fleet.stack_params(params),
+        closed_loop=mode == "bursty", n_logical=N_LOGICAL, hostcache=hc,
+        trim_pads=trim_pads)
+
+
+def _assert_same_run(ref, got):
+    (lat_r, st_r), (lat_g, st_g) = ref, got
+    assert np.array_equal(np.asarray(lat_r), np.asarray(lat_g))
+    assert jax.tree.structure(st_r) == jax.tree.structure(st_g)
+    for (path, a), b in zip(jax.tree_util.tree_flatten_with_path(st_r)[0],
+                            jax.tree.leaves(st_g)):
+        assert np.array_equal(np.asarray(a), np.asarray(b)), \
+            jax.tree_util.keystr(path)
+
+
+# the benchmark's tier (dm-writecache: write-only allocation, 50/45%)
+_WB_PW = HostCacheSpec(mode="wb", promote="write", wm_hi=0.5, wm_lo=0.45)
+_TRIM_LEN = TRIM_QUANTUM + 2048     # one scanned quantum, then 2048 pads
+
+
+@pytest.fixture(scope="module")
+def tail_traces():
+    """Per mode, two cells with pad tails of different lengths: hm_0's
+    live ops fill the scanned quantum, hm_1's stop at 5,000, so its pads
+    start inside the scanned prefix."""
+    out = {}
+    for mode in ("daily", "bursty"):
+        out[mode] = [
+            _with_tail(make_trace(name, N_LOGICAL, mode=mode,
+                                  capacity_pages=CFG.total_pages),
+                       n_live, _TRIM_LEN)
+            for name, n_live in (("hm_0", TRIM_QUANTUM), ("hm_1", 5000))]
+    return out
+
+
+class TestTierTrim:
+    """A tier fleet's trimmed scan (`fleet._run_tier_trim`) equals its
+    full-length scan bit for bit: the host carry, the device carry and
+    the latency."""
+
+    @pytest.mark.parametrize("mode", ["daily", "bursty"])
+    @pytest.mark.parametrize("hc,policy", [
+        (_WB_PW, "coop"),
+        (HostCacheSpec(mode="wb", flush="watermark"), "baseline"),
+        (HostCacheSpec(mode="wb", promote="nth", promote_n=2.0), "ips"),
+        (HostCacheSpec(mode="wb", flush="idle"), "ips_agc"),
+        (HostCacheSpec(mode="wt"), "baseline"),
+        (HostCacheSpec(mode="wa"), "ips")],
+        ids=lambda v: v.tag if isinstance(v, HostCacheSpec) else v)
+    def test_trimmed_tier_fleet_is_bit_identical(self, tail_traces, hc,
+                                                 policy, mode):
+        traces = tail_traces[mode]
+        assert fleet._trim_len(np.stack(
+            [t["is_write"] for t in traces])) == TRIM_QUANTUM
+        _assert_same_run(
+            _tier_fleet(policy, traces, mode, hc, trim_pads=False),
+            _tier_fleet(policy, traces, mode, hc, trim_pads=True))
+
+    def test_trimmed_tier_fleet_matches_per_op_cells(self, tail_traces):
+        traces = tail_traces["daily"]
+        lat_f, st_f = _tier_fleet("coop", traces, "daily", _WB_PW,
+                                  trim_pads=True)
+        for i, tr in enumerate(traces):
+            got = jax.tree.map(lambda x: x[i], (lat_f, st_f))
+            _assert_same_run(_run_hc("coop", tr, "daily", _WB_PW), got)
+
+    def test_watermark_latch_settles_on_the_first_pad(self):
+        """The last live op arms a flush burst that drains the tier to
+        its low watermark: the latch the scanned prefix leaves is 1, and
+        the first tail pad, past the scanned prefix, clears it."""
+        hc = HostCacheSpec(mode="wb", flush="watermark", sets=8, ways=2,
+                           wm_hi=0.5, wm_lo=0.25)      # 8 and 4 lines
+        n_fill = TRIM_QUANTUM - 10
+        # one read miss allocates a clean line, read hits keep the clock
+        # going, 8 write misses arm the latch (2 lines flushed), and one
+        # more read hit flushes 2 more: 4 dirty lines, at wm_lo exactly
+        lba = np.array([8] * (n_fill + 1) + list(range(8)) + [8], np.int32)
+        kind = np.array([0] * (n_fill + 1) + [1] * 8 + [0], np.int32)
+        live = {"arrival_ms": 0.5 * np.arange(TRIM_QUANTUM,
+                                              dtype=np.float32),
+                "lba": lba, "is_write": kind}
+        _, st_live = _run_hc("ips", live, "daily", hc)
+        assert int(st_live.hostcache.flushing) == 1
+        assert int(st_live.hostcache.dirty_n) == 4
+        traces = [_with_tail(live, TRIM_QUANTUM, TRIM_QUANTUM + 1024)]
+        ref = _tier_fleet("ips", traces, "daily", hc, trim_pads=False)
+        got = _tier_fleet("ips", traces, "daily", hc, trim_pads=True)
+        assert int(got[1].hostcache.flushing[0]) == 0
+        _assert_same_run(ref, got)
+
+
+class TestTierTrimRouting:
+    """The sweep runner sends a tier group with a pad tail to the
+    trimmed program, and keeps timeline and wear-tracked tier groups on
+    the full-length scan."""
+
+    CSV = os.path.join(os.path.dirname(__file__), "data", "sample_msr.csv")
+    MAX_OPS = TRIM_QUANTUM + 4096       # 609 live ops, then pads
+
+    def _sweep(self, pts, **kw):
+        from repro import workloads
+        from repro.sweep.runner import run_sweep
+        from repro.telemetry.spans import Tracer
+        timings, tracer = [], Tracer()
+        with tracer.activate():
+            res = run_sweep(CFG, pts, max_ops=self.MAX_OPS, timings=timings,
+                            trace_cache=workloads.TraceCache(use_disk=False),
+                            **kw)
+        scans = [s["args"] for s in tracer.spans
+                 if s["name"] == "device.scan"]
+        assert len(timings) == len(scans) == 1
+        return res, timings[0], scans[0]
+
+    def test_a_tier_group_with_a_pad_tail_trims(self):
+        hc = HostCacheSpec(mode="wb", flush="idle", sets=64)
+        pt = SweepPoint(self.CSV, "daily", "ips_agc", hostcache=hc)
+        c0 = fleet.compile_count()
+        res, row, scan = self._sweep([pt])
+        assert fleet.compile_count() == c0 + 1
+        assert (row["t_scan"], row["t_len"], row["exec_path"]) == \
+            (TRIM_QUANTUM, self.MAX_OPS, "segment")
+        assert (scan["t_scan"], scan["t_len"], scan["hostcache"]) == \
+            (TRIM_QUANTUM, self.MAX_OPS, hc.tag)
+        # the same shapes with other traced knobs compile nothing new
+        c1 = fleet.compile_count()
+        knobs = SweepPoint(self.CSV, "daily", "ips_agc", hostcache=hc,
+                           cache_frac=0.5, idle_threshold_ms=20.0)
+        _, row, _ = self._sweep([knobs])
+        assert fleet.compile_count() == c1 and row["compiles"] == 0
+        assert row["t_scan"] == TRIM_QUANTUM
+        # and the untrimmed scan gives the same results
+        full, row, _ = self._sweep([pt], trim_pads=False)
+        assert row["t_scan"] == self.MAX_OPS
+        assert full == res
+
+    @pytest.mark.parametrize("group", ["timeline", "endurance"])
+    def test_timeline_and_wear_tier_groups_scan_full_length(self, group):
+        from repro.core.ssd.endurance.spec import EnduranceSpec
+        hc = HostCacheSpec(mode="wb", flush="idle", sets=64)
+        kw, endurance = {}, None
+        if group == "timeline":
+            kw["timeline_ops"] = 1024
+        else:
+            endurance = EnduranceSpec()
+        pt = SweepPoint(self.CSV, "daily", "ips", hostcache=hc,
+                        endurance=endurance)
+        _, row, scan = self._sweep([pt], **kw)
+        assert row["t_scan"] == row["t_len"] == self.MAX_OPS
+        assert row["exec_path"] == "per_op"
+        assert scan["t_scan"] == scan["t_len"] == self.MAX_OPS
 
 
 class TestSpecAndGrid:

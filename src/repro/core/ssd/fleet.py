@@ -31,7 +31,7 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Sequence
+from typing import NamedTuple, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -40,6 +40,7 @@ import numpy as np
 from repro.core.ssd.config import SSDConfig
 from repro.core.ssd.policies import resolve_spec, tracked_region
 from repro.core.ssd.policies.engine import _build_core, reduced_of
+from repro.core.ssd.policies.state import can_pack
 from repro.core.ssd.sim import (CellParams, SimState, flush_cache,
                                 init_state, make_step, replay_pads,
                                 replay_pads_windowed, summarize)
@@ -47,8 +48,9 @@ from repro.telemetry import spans
 from repro.workloads.compress import TRIM_QUANTUM
 
 __all__ = ["stack_params", "stack_ops", "shard_cells", "init_fleet_state",
-           "run_fleet", "flush_fleet", "summarize_fleet", "compile_count",
-           "cell_quantum", "shard_skip_count"]
+           "FleetPlan", "plan_fleet", "run_fleet", "flush_fleet",
+           "summarize_fleet", "compile_count", "cell_quantum",
+           "shard_skip_count"]
 
 # cumulative count of shard_cells calls that fell back to one device
 # because the cell axis did not divide the mesh — a structured signal
@@ -134,6 +136,18 @@ def init_fleet_state(cfg: SSDConfig, n_logical: int, n_cells: int, *,
         jnp.arange(n_cells))
 
 
+def _cell_step(cfg: SSDConfig, spec, hostcache, *, closed_loop: bool,
+               params: CellParams):
+    """One cell's scan step: the host tier's pipeline in front of the
+    device when `hostcache` is given (DESIGN.md §14), else the device
+    step itself."""
+    if hostcache is None:
+        return make_step(cfg, spec, closed_loop=closed_loop, params=params)
+    from repro.hostcache.pipeline import build_tier_step
+    return build_tier_step(cfg, spec, hostcache, closed_loop=closed_loop,
+                           params=params)
+
+
 @functools.partial(jax.jit, static_argnames=("cfg", "spec", "closed_loop",
                                              "timeline_ops", "hostcache"),
                    donate_argnums=(2,))
@@ -143,14 +157,8 @@ def _run_fleet(cfg: SSDConfig, spec, state0: SimState, ops: dict,
     endurance = params.endurance is not None
 
     def one(cell_state, cell_ops, cell_params):
-        if hostcache is not None:
-            from repro.hostcache.pipeline import build_tier_step
-            step = build_tier_step(cfg, spec, hostcache,
-                                   closed_loop=closed_loop,
-                                   params=cell_params)
-        else:
-            step = make_step(cfg, spec, closed_loop=closed_loop,
-                             params=cell_params)
+        step = _cell_step(cfg, spec, hostcache, closed_loop=closed_loop,
+                          params=cell_params)
         if timeline_ops is None:
             final, latency = jax.lax.scan(step, cell_state, cell_ops)
             return latency, final
@@ -194,11 +202,13 @@ def cell_quantum(cell_bucket: int | None = None) -> int:
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "spec", "closed_loop",
-                                             "n_pad", "timeline_ops"),
+                                             "n_pad", "timeline_ops",
+                                             "hostcache"),
                    donate_argnums=(2,))
 def _run_fleet_trim(cfg: SSDConfig, spec, state0: SimState, ops: dict,
                     params: CellParams, pad_t, *, closed_loop: bool,
-                    n_pad: int, timeline_ops: int | None = None):
+                    n_pad: int, timeline_ops: int | None = None,
+                    hostcache=None):
     """The trimmed fleet scan: `ops` hold only the (C, T_trim) prefix;
     the `n_pad` identical tail pads every cell shares are re-applied to
     their exact fixed point by `sim.replay_pads` (vmapped — cells
@@ -206,23 +216,32 @@ def _run_fleet_trim(cfg: SSDConfig, spec, state0: SimState, ops: dict,
     in place). Latency for the tail is literal zeros, appended by the
     caller outside the jit.
 
-    `timeline_ops` (static) keeps telemetry on the trimmed fast path
-    (DESIGN.md §13): the probe rows cover the scanned prefix, the
-    replayed tail snapshots counters at the remaining window boundaries
-    (`sim.replay_pads_windowed`), and `probe.windowed_prefix` assembles
-    the same per-window series the full-length scan produces —
-    bit-identical window for window. Positional windows need no lane
-    alignment here (per-op rows exist over the prefix), so any window
-    size works."""
+    A host-tier fleet (`hostcache`, DESIGN.md §14): a trace pad is a
+    fixed point of the tier, except that the watermark latch may clear
+    on the first pad. So that pad runs as one tier step, and the
+    remaining K·(n_pad − 1) device sub-ops go to `sim.replay_pads`.
+
+    `timeline_ops` (static, plain fleets only) keeps telemetry on the
+    trimmed fast path (DESIGN.md §13): the probe rows cover the scanned
+    prefix, the replayed tail snapshots counters at the remaining window
+    boundaries (`sim.replay_pads_windowed`), and `probe.windowed_prefix`
+    assembles the same per-window series the full-length scan produces —
+    bit-identical window for window, for any window size."""
     def one(cell_state, cell_ops, cell_params, cell_pad_t):
-        step = make_step(cfg, spec, closed_loop=closed_loop,
-                         params=cell_params)
+        step = _cell_step(cfg, spec, hostcache, closed_loop=closed_loop,
+                          params=cell_params)
         core = _build_core(cfg, spec, closed_loop=closed_loop,
                            params=cell_params)
         if timeline_ops is None:
             final, latency = jax.lax.scan(step, cell_state, cell_ops)
+            n_replay = n_pad
+            if hostcache is not None:
+                final, _ = step(final, {"arrival_ms": cell_pad_t,
+                                        "lba": jnp.int32(0),
+                                        "is_write": jnp.int32(-1)})
+                n_replay = (2 + hostcache.flush_per_op) * (n_pad - 1)
             red = replay_pads(core, reduced_of(final), final.loc[0],
-                              final.loc_ep[0], cell_pad_t, n_pad)
+                              final.loc_ep[0], cell_pad_t, n_replay)
             wtl = None
         else:
             from repro.telemetry import probe
@@ -245,64 +264,21 @@ def _run_fleet_trim(cfg: SSDConfig, spec, state0: SimState, ops: dict,
                                  jnp.full((n_pad,), cell_pad_t,
                                           jnp.float32)]),
                 window_ops=timeline_ops, t_len=t_len, t_scan=t_scan)
-        final = final._replace(
-            busy=red.busy, slc_used=red.slc_used, rp_done=red.rp_done,
-            trad_used=red.trad_used, valid_mig=red.valid_mig,
-            epoch=red.epoch, counters=red.counters, prev_t=red.prev_t,
-            idle_cum=red.idle_cum, idle_seen=red.idle_seen,
-            timeline=wtl)
-        return latency, final
-
-    return jax.vmap(one)(state0, ops, params, pad_t)
-
-
-@functools.partial(jax.jit, static_argnames=("cfg", "spec", "closed_loop",
-                                             "n_pad", "hostcache"),
-                   donate_argnums=(2,))
-def _run_tier_trim(cfg: SSDConfig, spec, state0: SimState, ops: dict,
-                   params: CellParams, pad_t, *, closed_loop: bool,
-                   n_pad: int, hostcache):
-    """The trimmed scan of a host-tier fleet (DESIGN.md §14): `ops` hold
-    only the (C, T_trim) prefix, and the `n_pad` identical tail pads are
-    replayed to their exact fixed point.
-
-    A trace pad is a fixed point of the host tier: it is not live, so it
-    hits, inserts and flushes nothing, and moves no clock; its K device
-    sub-ops are all pads, and its latency is exactly 0.0. Only the
-    watermark latch may change on the first pad, where it re-reads the
-    dirty count the last live op's flush left. So the first pad runs as
-    a whole tier step, and the remaining K·(n_pad − 1) device sub-ops,
-    identical pads, go to `sim.replay_pads` on the reduced carry."""
-    from repro.hostcache.pipeline import build_tier_step
-    k = 2 + hostcache.flush_per_op
-
-    def one(cell_state, cell_ops, cell_params, cell_pad_t):
-        step = build_tier_step(cfg, spec, hostcache,
-                               closed_loop=closed_loop, params=cell_params)
-        core = _build_core(cfg, spec, closed_loop=closed_loop,
-                           params=cell_params)
-        final, latency = jax.lax.scan(step, cell_state, cell_ops)
-        final, _ = step(final, {"arrival_ms": cell_pad_t,
-                                "lba": jnp.int32(0),
-                                "is_write": jnp.int32(-1)})
-        red = replay_pads(core, reduced_of(final), final.loc[0],
-                          final.loc_ep[0], cell_pad_t, k * (n_pad - 1))
-        return latency, final._replace(**red._asdict())
+        return latency, final._replace(timeline=wtl, **red._asdict())
 
     return jax.vmap(one)(state0, ops, params, pad_t)
 
 
 def compile_count() -> int:
     """Fleet-scan compilations so far in this process: the sizes of the
-    `_run_fleet`, `_run_fleet_trim` and `_run_tier_trim` jit caches,
-    keyed on (cfg, composition, mode) and the stacked (C, T) array
-    shapes — including the carry dtypes, so packed and unpacked fleets
-    compile separately. Traced-knob variation (CellParams values,
-    endurance weights/budgets) never grows it. The search engine
-    (repro.search) records per-round deltas of this in BENCH_search.json
-    and asserts knob-only rounds add zero."""
-    return (_run_fleet._cache_size() + _run_fleet_trim._cache_size()
-            + _run_tier_trim._cache_size())
+    `_run_fleet` and `_run_fleet_trim` jit caches, keyed on (cfg,
+    composition, mode, host tier) and the stacked (C, T) array shapes —
+    including the carry dtypes, so packed and unpacked fleets compile
+    separately. Traced-knob variation (CellParams values, endurance
+    weights/budgets) never grows it. The search engine (repro.search)
+    records per-round deltas of this in BENCH_search.json and asserts
+    knob-only rounds add zero."""
+    return _run_fleet._cache_size() + _run_fleet_trim._cache_size()
 
 
 def _trim_len(is_write: np.ndarray, quantum: int = TRIM_QUANTUM) -> int:
@@ -318,10 +294,35 @@ def _trim_len(is_write: np.ndarray, quantum: int = TRIM_QUANTUM) -> int:
     return min(-(-n_live // quantum) * quantum, t_len)
 
 
+class FleetPlan(NamedTuple):
+    """The regime a fleet runs (DESIGN.md §12): it scans `t_scan` of its
+    steps — fewer than all means the trimmed program replays the pad
+    tail — and carries its integer plane fields int16 when `packed`."""
+    t_scan: int
+    packed: bool
+
+
+def plan_fleet(cfg: SSDConfig, n_logical: int, is_write,
+               params: CellParams, *, hostcache=None,
+               timeline_ops: int | None = None) -> FleetPlan:
+    """Decide a fleet's regime from its (C, T) `is_write` and
+    (C,)-stacked `params`: trim to the shared live prefix (`_trim_len`)
+    unless the fleet tracks wear (tail reclamation keeps erasing into
+    the wear state) or is a host tier with a timeline (its host windows
+    need a per-op row to the end); pack when every cell passes
+    `policies.state.can_pack` and the fleet has no host tier."""
+    t_scan = is_write.shape[1]
+    if params.endurance is None and (hostcache is None
+                                     or timeline_ops is None):
+        t_scan = _trim_len(np.asarray(is_write))
+    return FleetPlan(t_scan, hostcache is None
+                     and can_pack(cfg, n_logical, params))
+
+
 def run_fleet(cfg: SSDConfig, policy, ops: dict, params: CellParams,
               *, closed_loop: bool, n_logical: int,
-              timeline_ops: int | None = None, trim_pads: bool = False,
-              packed: bool = False, hostcache=None):
+              timeline_ops: int | None = None, hostcache=None,
+              plan: FleetPlan | None = None):
     """Simulate a whole (composition, mode) fleet in one compiled scan.
 
     ops: (C, T) stacked op tensors from `stack_ops`; params: (C,)-stacked
@@ -331,54 +332,33 @@ def run_fleet(cfg: SSDConfig, policy, ops: dict, params: CellParams,
     `timeline_ops` attaches the per-cell telemetry probe (DESIGN.md §11);
     every cell windows identically over the shared padded length, so the
     final state's `timeline` leaves stack along C like any other field.
-
-    Raw-speed knobs (DESIGN.md §12), both default-off so existing callers
-    — notably the search engine's compile-count contract — see no change:
-    `trim_pads` scans only the shared live prefix and replays the all-pad
-    tail to its exact fixed point — telemetry runs stay on it too (the
-    tail replay snapshots counters at the remaining window boundaries,
-    DESIGN.md §13); only endurance runs skip it (tail reclamation keeps
-    erasing into the wear state); `packed` shrinks the donated carry to
-    int16 plane fields (gate on `policies.state.can_pack`). Results are
-    bit-identical either way (tests/test_compress.py).
-
     `hostcache` (static: a `HostCacheSpec`, or None) stacks the host
-    block-cache tier in front of every cell (DESIGN.md §14). `trim_pads`
-    trims such a fleet too (`_run_tier_trim`), unless its telemetry
-    probe is on: the host windows need a per-op row over the whole
-    length (tests/test_hostcache.py)."""
+    block-cache tier in front of every cell (DESIGN.md §14).
+
+    The fleet runs the regime `plan_fleet` decides: the trimmed program
+    when it scans short, else the full-length `_run_fleet`. Pass `plan`
+    to run another — the sweep runner passes the one it records, and
+    tests pass the full-length or unpacked reference. Results are
+    bit-identical in every regime."""
     spec = resolve_spec(policy)
-    n_cells = ops["lba"].shape[0]
-    endurance = params.endurance is not None
-    if (trim_pads and not endurance
-            and (hostcache is None or timeline_ops is None)):
-        is_w = np.asarray(ops["is_write"])
-        t_len = is_w.shape[1]
-        t_trim = _trim_len(is_w)
-        if t_trim < t_len:
-            state0 = shard_cells(init_fleet_state(
-                cfg, n_logical, n_cells, timeline=timeline_ops,
-                packed=packed, hostcache=hostcache))
-            ops_trim = {k: v[:, :t_trim] for k, v in ops.items()}
-            pad_t = jnp.asarray(ops["arrival_ms"][:, t_trim], jnp.float32)
-            if hostcache is None:
-                latency, final = _run_fleet_trim(
-                    cfg, spec, state0, ops_trim, params, pad_t,
-                    closed_loop=closed_loop, n_pad=t_len - t_trim,
-                    timeline_ops=timeline_ops)
-            else:
-                latency, final = _run_tier_trim(
-                    cfg, spec, state0, ops_trim, params, pad_t,
-                    closed_loop=closed_loop, n_pad=t_len - t_trim,
-                    hostcache=hostcache)
-            latency = jnp.pad(latency, ((0, 0), (0, t_len - t_trim)))
-            return latency, final
+    n_cells, t_len = ops["lba"].shape
+    if plan is None:
+        plan = plan_fleet(cfg, n_logical, ops["is_write"], params,
+                          hostcache=hostcache, timeline_ops=timeline_ops)
     state0 = shard_cells(init_fleet_state(
-        cfg, n_logical, n_cells, endurance=endurance,
-        timeline=timeline_ops, packed=packed, hostcache=hostcache))
-    return _run_fleet(cfg, spec, state0, ops, params,
-                      closed_loop=closed_loop, timeline_ops=timeline_ops,
-                      hostcache=hostcache)
+        cfg, n_logical, n_cells, endurance=params.endurance is not None,
+        timeline=timeline_ops, packed=plan.packed, hostcache=hostcache))
+    if plan.t_scan == t_len:
+        return _run_fleet(cfg, spec, state0, ops, params,
+                          closed_loop=closed_loop, timeline_ops=timeline_ops,
+                          hostcache=hostcache)
+    n_pad = t_len - plan.t_scan
+    ops_trim = {k: v[:, :plan.t_scan] for k, v in ops.items()}
+    pad_t = jnp.asarray(ops["arrival_ms"][:, plan.t_scan], jnp.float32)
+    latency, final = _run_fleet_trim(
+        cfg, spec, state0, ops_trim, params, pad_t, closed_loop=closed_loop,
+        n_pad=n_pad, timeline_ops=timeline_ops, hostcache=hostcache)
+    return jnp.pad(latency, ((0, 0), (0, n_pad))), final
 
 
 def flush_fleet(cfg: SSDConfig, states: SimState, policy) -> SimState:

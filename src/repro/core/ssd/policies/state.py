@@ -16,6 +16,7 @@ from __future__ import annotations
 from typing import NamedTuple
 
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.ssd.endurance.model import (EnduranceParams, WearState,
                                             as_params, init_wear)
@@ -111,16 +112,16 @@ INT16_MAX = 32767
 def can_pack(cfg, n_logical: int, params: CellParams) -> bool:
     """True when every integer plane field provably fits int16, so
     `init_state(packed=True)` is exact (host-side check on concrete
-    caps). Bounds: `slc_used <= cap_basic + cap_boost` (allocation cap),
+    caps; (C,)-stacked params pass when every cell does). Bounds:
+    `slc_used <= cap_basic + cap_boost` (allocation cap),
     `rp_done <= 2 * slc_used` (two reprograms per SLC page),
     `trad_used <= cap_trad`, and `valid_mig <= ceil(n_logical / P)` (an
     lba's cached copy always lives on plane `lba % P`, so a plane can
     hold at most that many valid entries). `epoch` needs no bound — it
     wraps congruent with the int16 `loc_ep` stamps."""
-    cap_basic = int(params.cap_basic)
-    cap_trad = int(params.cap_trad)
-    cap_boost = 0 if params.cap_boost is None else int(params.cap_boost)
-    bound = max(2 * (cap_basic + cap_boost), cap_trad,
+    basic, trad = np.asarray(params.cap_basic), np.asarray(params.cap_trad)
+    boost = 0 if params.cap_boost is None else np.asarray(params.cap_boost)
+    bound = max(int(np.max(2 * (basic + boost))), int(np.max(trad)),
                 ceil_div(n_logical, cfg.num_planes))
     return bound <= INT16_MAX
 

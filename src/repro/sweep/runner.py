@@ -10,9 +10,8 @@ compiled program. Each group becomes ONE `fleet.run_fleet` call: a
 
 Dispatch is meant to be async: jax returns futures, so the runner first
 dispatches every group back-to-back and only then blocks on results,
-group by group, converting to numpy (`max_pending` bounds the window of
-live dispatched buffers for memory-constrained hosts). It overlaps less
-than that suggests. Every trace is built in the grouping loop
+group by group, converting to numpy. It overlaps less than that
+suggests. Every trace is built in the grouping loop
 (`sweep.group`), before the first dispatch, while the device holds
 nothing. And on a TPU v5e a fleet's `sweep.dispatch` returns only when
 its eager flush and summary are about done on the device: the host waits
@@ -29,6 +28,9 @@ path, all built through the content-addressed compiled-trace cache
 (`workloads.TraceCache`) — one build per (spec, seed, mode, repeat) recipe
 per process, memoized on disk across runs. Pass `trace_cache=` to inspect
 hit/miss counts (the CLI logs them into `BENCH_*` run metadata).
+
+Each fleet runs the regime `fleet.plan_fleet` decides (trimmed scan,
+packed carry; DESIGN.md §12), and its timings row records that plan.
 
 `driver.eval_cell` remains the single-cell reference path; equivalence is
 bit-for-bit (tests/test_fleet.py) because both paths run the same
@@ -55,7 +57,6 @@ from repro.core.ssd.driver import (LOGICAL_SPACE_CAP, _agc_waste_p,
                                    agc_waste_from_stats)
 from repro.core.ssd.endurance.spec import EnduranceSpec
 from repro.core.ssd.policies import get_spec, requires_endurance
-from repro.core.ssd.policies.state import can_pack
 from repro.core.ssd.sim import default_params
 from repro.sweep.grid import SweepPoint
 from repro.telemetry.spans import active_tracer, span
@@ -203,12 +204,9 @@ def run_sweep(cfg: SSDConfig, points: Sequence[SweepPoint], *,
               progress=None,
               trace_cache: Optional[workloads.TraceCache] = None,
               timings: Optional[List[Dict]] = None,
-              max_pending: Optional[int] = None,
               cell_bucket: Optional[int] = None,
               timeline_ops: Optional[int] = None,
-              timelines: Optional[Dict] = None,
-              trim_pads: bool = True,
-              packed: bool | str = "auto"
+              timelines: Optional[Dict] = None
               ) -> Dict[SweepPoint, Dict[str, float]]:
     """Run every sweep point batched; returns {point: metrics}.
 
@@ -217,11 +215,6 @@ def run_sweep(cfg: SSDConfig, points: Sequence[SweepPoint], *,
     compiled-trace cache (a fresh one per call otherwise). `timings`, if
     given, is a list the runner appends one dict per compilation group to:
     policies, mode, composition, cells, t_len, dispatch_s, block_s.
-    `max_pending` bounds the async-dispatch window: at most that many
-    groups' dispatched buffers stay live before the runner drains the
-    oldest (None — the default — dispatches every group before blocking;
-    set it on memory-constrained hosts with very large grids, where
-    group-count x (C, T) op tensors would multiply peak host RAM).
     `cell_bucket` quantizes each group's padded cell count to a multiple
     of the bucket (on top of the device-count multiple): the compiled
     fleet is keyed on the stacked (C, T) shapes, so repeated sweeps whose
@@ -246,20 +239,10 @@ def run_sweep(cfg: SSDConfig, points: Sequence[SweepPoint], *,
     compilations that group's dispatch triggered, `devices`: how many
     devices its stacked cells were laid over, plus the group's
     throughput (`ops_per_s` over the padded length, `cells_per_s`) and
-    which raw-speed knobs applied (`t_scan`, `packed`).
-
-    Raw-speed defaults (DESIGN.md §12): `trim_pads=True` scans only each
-    group's shared live prefix and replays the identical all-pad tail to
-    its exact fixed point — telemetry groups stay on it (segment-aware
-    windows, DESIGN.md §13), and host-tier groups without one
-    (DESIGN.md §14); endurance groups and host-tier groups with a
-    timeline take the full path (a one-line warning marks the endurance
-    fallback when a timeline was requested, and each timings row records
-    which `exec_path` ran);
-    `packed="auto"` carries int16 plane fields
-    whenever every cell's caps provably fit (`policies.state.can_pack`),
-    `True`/`False` force it. Results are bit-identical either way —
-    committed BENCH geomeans are the regression gate."""
+    the fleet's plan (`t_scan`, `packed`, and `exec_path`: "segment"
+    when it scanned short, else "per_op"; `fleet.plan_fleet`,
+    DESIGN.md §12). A one-line warning marks an endurance group with a
+    timeline, which forgoes the trimmed scan."""
     import jax
 
     n_logical = _n_logical(cfg)
@@ -390,8 +373,6 @@ def run_sweep(cfg: SSDConfig, points: Sequence[SweepPoint], *,
         for (spec, mode, _t_len, _endur, _hc), pts in sorted(
                 groups.items(), key=lambda kv: (kv[0][0], kv[0][1], kv[0][2],
                                                 kv[0][3], str(kv[0][4]))):
-            if max_pending is not None and len(pending) >= max_pending:
-                drain(pending.pop(0))       # bounded window: free the oldest
             traces = [cell_trace(p) for p in pts]
             params = [_cell_params(cfg, p, cell_waste(p)) for p in pts]
             # pad the cell axis to a device-count multiple so shard_cells can
@@ -404,18 +385,7 @@ def run_sweep(cfg: SSDConfig, points: Sequence[SweepPoint], *,
             params += [params[-1]] * pad
 
             names = ",".join(sorted({p.policy for p in pts}))
-            # packing decision is per group (it keys the compiled carry):
-            # every cell's caps must provably fit int16
-            pack_grp = (packed if isinstance(packed, bool)
-                        else all(can_pack(cfg, n_logical, p) for p in params))
-            if _hc is not None:
-                # the tier pipeline runs unpacked; it trims its pad tail
-                # unless the probe is on, whose host windows need a per-op
-                # row to the end (DESIGN.md §14)
-                pack_grp = False
-            trim_grp = (trim_pads and not _endur
-                        and (_hc is None or timeline_ops is None))
-            if timeline_ops is not None and trim_pads and _endur:
+            if timeline_ops is not None and _endur:
                 # the fallback used to be silent — a fleet that quietly
                 # forfeits the fast path just looks "slow" (DESIGN.md §13)
                 import warnings
@@ -437,13 +407,13 @@ def run_sweep(cfg: SSDConfig, points: Sequence[SweepPoint], *,
                         cells=n_cells, t_len=_t_len, **tier) as rec:
                 ops = fleet.shard_cells(fleet.stack_ops(traces))
                 stacked = fleet.shard_cells(fleet.stack_params(params))
-                t_scan = (fleet._trim_len(np.asarray(ops["is_write"]))
-                          if trim_grp else _t_len)
+                plan = fleet.plan_fleet(cfg, n_logical, ops["is_write"],
+                                        stacked, hostcache=_hc,
+                                        timeline_ops=timeline_ops)
                 latency, states = fleet.run_fleet(
                     cfg, spec, ops, stacked,
                     closed_loop=(mode == "bursty"), n_logical=n_logical,
-                    timeline_ops=timeline_ops, trim_pads=trim_grp,
-                    packed=pack_grp, hostcache=_hc)
+                    timeline_ops=timeline_ops, hostcache=_hc, plan=plan)
                 t_fleet = time.perf_counter()
                 scan_done = (None if watcher is None else watcher.watch(
                     jax.tree_util.tree_leaves(states)[0]))
@@ -456,10 +426,10 @@ def run_sweep(cfg: SSDConfig, points: Sequence[SweepPoint], *,
             pending.append({"pts": pts, "n_ops": [t["n_ops"] for t in traces],
                             "summ": summ, "names": names, "mode": mode,
                             "spec": spec, "t_len": _t_len, "pad": pad,
-                            "t_scan": t_scan, "packed": pack_grp,
+                            "t_scan": plan.t_scan, "packed": plan.packed,
                             "tier": tier,
                             "devices": len(ops["lba"].sharding.device_set),
-                            "exec_path": ("segment" if t_scan < _t_len
+                            "exec_path": ("segment" if plan.t_scan < _t_len
                                           else "per_op"),
                             "dispatch_s": rec["dur_s"],
                             "compiles": rec["args"]["compiles"],

@@ -159,7 +159,7 @@ def test_tier_trim_fleet_compiles(topo, no_persistent_cache):
     n_cells, t_scan = 8, 57344
     state0, ops, params = _fleet_args(point, n_cells, t_scan, one)
     pad_t = jax.ShapeDtypeStruct((n_cells,), jnp.float32, sharding=one)
-    compiled = fleet._run_tier_trim.lower(
+    compiled = fleet._run_fleet_trim.lower(
         CFG, get_spec(point.policy), state0, ops, params, pad_t,
         closed_loop=False, n_pad=PAD_OPS - t_scan, hostcache=hc).compile()
     _fits_one_chip(compiled)

@@ -282,17 +282,47 @@ class TestFleetTrimAndPack:
         params = fleet.stack_params(
             [default_params(CFG, "ips_agc", 0.0) for _ in names])
         ops = fleet.stack_ops(traces)
+        t_len = ops["lba"].shape[1]
+        t_trim = fleet._trim_len(np.asarray(ops["is_write"]))
         lat_ref, st_ref = fleet.run_fleet(
             CFG, "ips_agc", ops, params, closed_loop=False,
-            n_logical=N_LOGICAL)
+            n_logical=N_LOGICAL, plan=fleet.FleetPlan(t_len, False))
         for trim in (True, False):
             lat_t, st_t = fleet.run_fleet(
                 CFG, "ips_agc", fleet.stack_ops(traces), params,
                 closed_loop=False, n_logical=N_LOGICAL,
-                trim_pads=trim, packed=True)
+                plan=fleet.FleetPlan(t_trim if trim else t_len, True))
             assert np.array_equal(np.asarray(lat_ref), np.asarray(lat_t))
             _assert_states_equal(st_ref, st_t,
                                  f"fleet trim={trim} packed")
+
+    @pytest.mark.parametrize("kind,tail,timeline,t_scan,packed", [
+        ("paper", True, None, TRIM_QUANTUM, True),
+        ("paper", False, None, 2 * TRIM_QUANTUM, True),
+        ("endurance", True, None, 2 * TRIM_QUANTUM, True),
+        ("tier", True, None, TRIM_QUANTUM, False),
+        ("tier", True, 1024, 2 * TRIM_QUANTUM, False),
+        ("paper", True, 1024, TRIM_QUANTUM, True)],
+        ids=["tail", "no-tail", "endurance", "tier", "tier-timeline",
+             "timeline"])
+    def test_plan_fleet(self, kind, tail, timeline, t_scan, packed):
+        """One rule a case: trim a pad tail unless the fleet tracks wear
+        or is a tier with a timeline; pack unless it has a tier."""
+        from repro.core.ssd.endurance.spec import EnduranceSpec
+        from repro.hostcache.model import as_hc_params
+        from repro.hostcache.spec import HostCacheSpec
+        hc = HostCacheSpec() if kind == "tier" else None
+        p = default_params(
+            CFG, "ips", 0.0,
+            endurance=EnduranceSpec() if kind == "endurance" else None)
+        if hc is not None:
+            p = p._replace(hostcache=as_hc_params(hc))
+        is_write = np.full((2, 2 * TRIM_QUANTUM), -1, np.int32)
+        is_write[:, :100 if tail else 2 * TRIM_QUANTUM] = 1
+        assert fleet.plan_fleet(
+            CFG, N_LOGICAL, is_write, fleet.stack_params([p, p]),
+            hostcache=hc, timeline_ops=timeline) == fleet.FleetPlan(
+                t_scan, packed)
 
     def test_trim_len(self):
         is_write = np.full((2, 4 * TRIM_QUANTUM), -1, np.int32)

@@ -360,13 +360,17 @@ def _with_tail(trace, n_live, t_len):
             np.full(n_pad, -1, np.int32)])}
 
 
-def _tier_fleet(policy, traces, mode, hc, *, trim_pads):
+def _tier_fleet(policy, traces, mode, hc, *, full=False):
+    """The tier fleet in the regime `fleet.plan_fleet` gives it, or in
+    the full-length reference when `full`."""
     params = [default_params(CFG, policy)._replace(
         hostcache=as_hc_params(hc))] * len(traces)
+    ops = fleet.stack_ops(traces)
+    t_len = ops["lba"].shape[1]
     return fleet.run_fleet(
-        CFG, policy, fleet.stack_ops(traces), fleet.stack_params(params),
+        CFG, policy, ops, fleet.stack_params(params),
         closed_loop=mode == "bursty", n_logical=N_LOGICAL, hostcache=hc,
-        trim_pads=trim_pads)
+        plan=fleet.FleetPlan(t_len, False) if full else None)
 
 
 def _assert_same_run(ref, got):
@@ -400,7 +404,7 @@ def tail_traces():
 
 
 class TestTierTrim:
-    """A tier fleet's trimmed scan (`fleet._run_tier_trim`) equals its
+    """A tier fleet's trimmed scan (`fleet._run_fleet_trim`) equals its
     full-length scan bit for bit: the host carry, the device carry and
     the latency."""
 
@@ -419,13 +423,12 @@ class TestTierTrim:
         assert fleet._trim_len(np.stack(
             [t["is_write"] for t in traces])) == TRIM_QUANTUM
         _assert_same_run(
-            _tier_fleet(policy, traces, mode, hc, trim_pads=False),
-            _tier_fleet(policy, traces, mode, hc, trim_pads=True))
+            _tier_fleet(policy, traces, mode, hc, full=True),
+            _tier_fleet(policy, traces, mode, hc))
 
     def test_trimmed_tier_fleet_matches_per_op_cells(self, tail_traces):
         traces = tail_traces["daily"]
-        lat_f, st_f = _tier_fleet("coop", traces, "daily", _WB_PW,
-                                  trim_pads=True)
+        lat_f, st_f = _tier_fleet("coop", traces, "daily", _WB_PW)
         for i, tr in enumerate(traces):
             got = jax.tree.map(lambda x: x[i], (lat_f, st_f))
             _assert_same_run(_run_hc("coop", tr, "daily", _WB_PW), got)
@@ -449,8 +452,8 @@ class TestTierTrim:
         assert int(st_live.hostcache.flushing) == 1
         assert int(st_live.hostcache.dirty_n) == 4
         traces = [_with_tail(live, TRIM_QUANTUM, TRIM_QUANTUM + 1024)]
-        ref = _tier_fleet("ips", traces, "daily", hc, trim_pads=False)
-        got = _tier_fleet("ips", traces, "daily", hc, trim_pads=True)
+        ref = _tier_fleet("ips", traces, "daily", hc, full=True)
+        got = _tier_fleet("ips", traces, "daily", hc)
         assert int(got[1].hostcache.flushing[0]) == 0
         _assert_same_run(ref, got)
 
@@ -481,7 +484,7 @@ class TestTierTrimRouting:
         hc = HostCacheSpec(mode="wb", flush="idle", sets=64)
         pt = SweepPoint(self.CSV, "daily", "ips_agc", hostcache=hc)
         c0 = fleet.compile_count()
-        res, row, scan = self._sweep([pt])
+        _, row, scan = self._sweep([pt])
         assert fleet.compile_count() == c0 + 1
         assert (row["t_scan"], row["t_len"], row["exec_path"]) == \
             (TRIM_QUANTUM, self.MAX_OPS, "segment")
@@ -494,10 +497,6 @@ class TestTierTrimRouting:
         _, row, _ = self._sweep([knobs])
         assert fleet.compile_count() == c1 and row["compiles"] == 0
         assert row["t_scan"] == TRIM_QUANTUM
-        # and the untrimmed scan gives the same results
-        full, row, _ = self._sweep([pt], trim_pads=False)
-        assert row["t_scan"] == self.MAX_OPS
-        assert full == res
 
     @pytest.mark.parametrize("group", ["timeline", "endurance"])
     def test_timeline_and_wear_tier_groups_scan_full_length(self, group):

@@ -181,15 +181,6 @@ class TestEveryPolicyEndToEnd:
             assert "+" in g["composition"]
             assert g["devices"] == len(jax.devices())
 
-    def test_bounded_dispatch_window_matches_unbounded(self):
-        from repro.sweep.runner import run_sweep
-        points = [SweepPoint(trace="hm_0", mode=m, policy=p)
-                  for m in ("bursty", "daily")
-                  for p in ("baseline", "ips")]
-        free = run_sweep(CFG, points, max_ops=1024)
-        bounded = run_sweep(CFG, points, max_ops=1024, max_pending=1)
-        assert free == bounded
-
 
 class TestBeyondPaperBehavior:
     def test_ips_lazy_equals_coop_closed_loop(self):

@@ -526,14 +526,15 @@ class TestSegmentWindows:
         params = fleet.stack_params(
             [default_params(CFG, "ips") for _ in traces])
         win = 480                      # NOT a SEG_LANES multiple: allowed
-        lat_f, st_f = fleet.run_fleet(CFG, "ips", ops, params,
-                                      closed_loop=False,
-                                      n_logical=N_LOGICAL,
-                                      timeline_ops=win)
+        t_len = ops["lba"].shape[1]
+        lat_f, st_f = fleet.run_fleet(
+            CFG, "ips", ops, params, closed_loop=False,
+            n_logical=N_LOGICAL, timeline_ops=win,
+            plan=fleet.FleetPlan(t_len, False))
         lat_t, st_t = fleet.run_fleet(CFG, "ips", ops, params,
                                       closed_loop=False,
                                       n_logical=N_LOGICAL,
-                                      timeline_ops=win, trim_pads=True)
+                                      timeline_ops=win)
         assert np.array_equal(np.asarray(lat_f), np.asarray(lat_t))
         _assert_timelines_equal(st_f.timeline, st_t.timeline, "fleet")
         for field in st_f._fields:
